@@ -41,11 +41,12 @@ The ``seed_before`` block is the measurement of the pre-engine host-driven
 solver (3-4 jitted dispatches + 3 blocking scalar syncs per outer iteration),
 taken on this container at the refactor commit; the ``engine_after`` block is
 re-measured on every run. The ``mesh_2x4`` block re-measures the same two
-benchmarks through the mesh-native engine on a 2x4 mesh of 8 forced host
-devices (in a subprocess: device count must be fixed before jax
-initializes); ``seed_distributed`` records the per-outer-iteration budget of
-the seed-era core/distributed.py host loop that the mesh-native engine
-replaced (counted from its code structure: scores/topk/gather/gram/inner/
+benchmarks through the mesh-native engine on a 2x4 mesh, in this process,
+over the devices it sees (on the CPU: run with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``);
+``seed_distributed`` records the per-outer-iteration budget of the seed-era
+core/distributed.py host loop that the mesh-native engine replaced
+(counted from its code structure: scores/topk/gather/gram/inner/
 scatter/apply_ws launches + kkt/gsupp/epochs blocking pulls).
 """
 from __future__ import annotations
@@ -53,8 +54,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -66,6 +65,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.core import (MCP, L1, BlockL1, MultitaskQuadratic, Quadratic,  # noqa: E402
                         lambda_max, make_engine, solve)
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.data.synth import make_correlated_design, make_sparse_design  # noqa: E402
 
 # measured once on the seed (pre-engine) solver, same container, same configs:
@@ -447,37 +447,21 @@ def _measure_telemetry_overhead(n_repeats=7):
     return rec
 
 
-_SHARDED_MARK = "BENCH_SHARDED_JSON:"
-
-
-def _child_sharded(scale):
-    """Runs inside the 8-device subprocess: measure the 2x4 mesh engine."""
-    from repro.launch.mesh import make_test_mesh
-    mesh = make_test_mesh((2, 4))
-    out = {}
-    for bench, cfg in CONFIGS[scale].items():
-        out[bench] = _measure(bench, cfg, mesh=mesh)
-    print(_SHARDED_MARK + json.dumps(out, default=float))
-
-
 def _measure_sharded(scale):
-    """Launch the 2x4-mesh measurement in a subprocess (the forced 8-device
-    host platform must be configured before jax initializes)."""
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_engine",
-         "--child-sharded", "--scale", scale],
-        capture_output=True, text=True, timeout=1800,
-        env={**os.environ,
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-             "PYTHONPATH": "src"})
-    if r.returncode != 0:
-        raise SystemExit(f"sharded bench subprocess failed:\n{r.stdout}"
-                         f"\n{r.stderr}")
-    for line in r.stdout.splitlines():
-        if line.startswith(_SHARDED_MARK):
-            return json.loads(line[len(_SHARDED_MARK):])
-    raise SystemExit(f"sharded bench subprocess emitted no result:"
-                     f"\n{r.stdout}\n{r.stderr}")
+    """Measure the 2x4 mesh engine in this process, over the devices it
+    sees: a chip host's own, or on the CPU the 8 host devices the caller
+    forces with XLA_FLAGS=--xla_force_host_platform_device_count=8 (one
+    process per chip — a JAX child could not reach a chip this process
+    holds)."""
+    from repro.launch.mesh import make_test_mesh
+    if len(jax.devices()) < 8:
+        raise SystemExit(
+            f"mesh_2x4 needs 8 devices, this process sees "
+            f"{len(jax.devices())}: on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=8, or pass --no-sharded")
+    mesh = make_test_mesh((2, 4))
+    return {bench: _measure(bench, cfg, mesh=mesh)
+            for bench, cfg in CONFIGS[scale].items()}
 
 
 def _check_budget(report, budget_path):
@@ -575,20 +559,15 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--out", default=None)
     ap.add_argument("--no-sharded", action="store_true",
-                    help="skip the 2x4-mesh subprocess measurement")
+                    help="skip the 2x4-mesh measurement")
     ap.add_argument("--no-sparse", action="store_true",
                     help="skip the sparse_fig2 CSC-native measurement")
     ap.add_argument("--check-budget", default=None, metavar="PATH",
                     help="fail if dispatches/outer exceed the budgets "
                          "recorded in PATH (committed BENCH_engine.json)")
-    ap.add_argument("--child-sharded", action="store_true",
-                    help=argparse.SUPPRESS)       # internal: subprocess mode
-    ap.add_argument("--scale", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
-    scale = args.scale or ("smoke" if args.smoke else "small")
-    if args.child_sharded:
-        _child_sharded(scale)
-        return
+    scale = "smoke" if args.smoke else "small"
+    enable_compile_cache()
     out_path = args.out or ("experiments/bench/BENCH_engine_smoke.json"
                             if args.smoke else "BENCH_engine.json")
 

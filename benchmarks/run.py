@@ -47,6 +47,8 @@ def main() -> None:
     if args.smoke:
         args.scale = "smoke"
         args.skip_roofline_report = True
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     names = [args.only] if args.only else BENCHES
     failures = []

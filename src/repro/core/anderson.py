@@ -17,7 +17,11 @@ def anderson_extrapolate(hist):
     U = flat[1:] - flat[:-1]                          # [M, KT]
     UUt = U @ U.T                                     # [M, M]
     scale = jnp.trace(UUt) / M
-    reg = 1e-10 * jnp.maximum(scale, 1e-30)
+    # Ridge relative to the Gram's scale. UUt carries rounding of order eps
+    # of its dtype, so a ridge below ~100 eps (float32: 1.2e-5) is a no-op
+    # that leaves a near-singular system; float64 keeps 1e-10.
+    rel = max(1e-10, 100.0 * float(jnp.finfo(flat.dtype).eps))
+    reg = rel * jnp.maximum(scale, 1e-30)
     z = jnp.linalg.solve(UUt + reg * jnp.eye(M, dtype=flat.dtype),
                          jnp.ones((M,), dtype=flat.dtype))
     denom = jnp.sum(z)

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.launch.mesh import shard_map
+from jax import shard_map
 from repro.launch.shardings import design_specs
 
 from .solver import SolveResult, solve

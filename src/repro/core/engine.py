@@ -60,14 +60,16 @@ so one compiled step per bucket serves the whole (fold x lambda) grid.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.launch.mesh import shard_map
+from jax import shard_map
 from repro.launch.shardings import design_specs, ring_spec, task_spec
 from repro.obs.registry import MetricsRegistry
 from repro.obs.rings import gap_supported, quadratic_l1_gap
@@ -83,6 +85,10 @@ __all__ = ["EngineConfig", "SolveEngine", "SubproblemSolver", "GramSolver",
            "XbSolver", "get_engine", "KERNEL_DATAFIT_KINDS", "Design",
            "DenseDesign", "as_design", "pack_support", "scatter_packed"]
 
+
+# explicit on the eager Design entry points (lambda_max, warm-start
+# residuals), which run outside the f32_matmuls-traced steps
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 # datafit class name -> kernels/cd_epoch.py datafit_kind tag (the Pallas Xb
 # kernel hard-codes the raw-gradient formula per kind)
@@ -106,6 +112,23 @@ PALLAS_MESH_ERROR = (
 PALLAS_SPARSE_ELL_ERROR = (
     "backend='pallas' on a sparse design requires the ELL score layout: "
     "build it with CSCDesign.from_scipy(X, ell=True); " + _PALLAS_MATRIX)
+PALLAS_SPARSE_TPU_ERROR = (
+    "backend='pallas' on a sparse design does not run on TPU: the ELL score "
+    "kernel (sparse/ops.py csc_score_pallas) does not compile for the chip "
+    "yet; " + _PALLAS_MATRIX + "; use backend='jax' (use_kernels=False) for "
+    "sparse solves on TPU")
+
+
+def f32_matmuls(fn):
+    """Trace `fn` with float32-accurate matmuls. On TPU, XLA's default
+    precision rounds float32 matmul operands to bfloat16 (about three
+    significant digits), which would put every score pass, Gram and residual
+    far outside any float32 KKT tolerance; on CPU it changes nothing."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return traced
 
 
 # --------------------------------------------------------- design abstraction
@@ -194,7 +217,7 @@ class DenseDesign(Design):
         return self
 
     def score(self, raw, backend="jax"):
-        return self.X.T @ raw
+        return jnp.matmul(self.X.T, raw, precision=_HIGHEST)
 
     def gather_ws(self, mine, loc_idx, model_axis):
         return gather_ws_cols(self.X, mine, loc_idx, model_axis), None
@@ -204,7 +227,7 @@ class DenseDesign(Design):
         return Xb + _apply_T(X_ws.T, delta)
 
     def matvec(self, beta):
-        return self.X @ beta
+        return jnp.matmul(self.X, beta, precision=_HIGHEST)
 
     def lipschitz(self, datafit, w=None, backend="jax"):
         del backend                     # dense reduction is already one pass
@@ -224,7 +247,6 @@ class DenseDesign(Design):
 
     def take_columns(self, idx):
         """Column subset with -1 entries as zero columns (screening pad)."""
-        import numpy as np
         idx = np.asarray(idx)
         Xn = np.asarray(self.X)
         out = Xn[:, np.where(idx < 0, 0, idx)]
@@ -243,15 +265,19 @@ def is_scipy_sparse(X) -> bool:
     return hasattr(X, "tocsc") and hasattr(X, "nnz")
 
 
-def as_design(X) -> Design:
+def as_design(X, on_host=False) -> Design:
     """Dispatch any accepted design input to a Design: Design instances pass
     through, scipy sparse matrices convert to CSC, everything else is a
-    dense array."""
+    dense array. ``on_host=True`` keeps a numpy array on the host, for a
+    caller that places it straight onto a mesh (``Design.place``) instead
+    of first copying all of it to one device."""
     if isinstance(X, Design):
         return X
     if is_scipy_sparse(X):
         from repro.sparse.matrix import CSCDesign
         return CSCDesign.from_scipy(X)
+    if on_host and isinstance(X, np.ndarray):
+        return DenseDesign(X)
     return DenseDesign(jnp.asarray(X))
 
 
@@ -560,9 +586,11 @@ class SolveEngine:
         self.metrics = MetricsRegistry()
         self.retraces: dict = {}
         self.n_dispatches = 0
-        self._jstep = jax.jit(self._outer_step, static_argnames=("bucket",))
-        self._jchunk = jax.jit(self._chunk_solve, static_argnames=("bucket",))
-        self._jprobe = jax.jit(self._probe)
+        self._jstep = jax.jit(f32_matmuls(self._outer_step),
+                              static_argnames=("bucket",))
+        self._jchunk = jax.jit(f32_matmuls(self._chunk_solve),
+                               static_argnames=("bucket",))
+        self._jprobe = jax.jit(f32_matmuls(self._probe))
 
     # legacy counter attributes are live views into the metrics registry
     # (DESIGN.md §11.3): the mutation idioms used everywhere —
@@ -1091,9 +1119,10 @@ class SolveEngine:
         supported matrix (datafit x penalty x dense/sparse/mesh/pallas) is
         in README.md. Since the fused-kernel generalization, the Pallas
         backend runs weighted, multitask (block-penalty), and chunked
-        solves; the two remaining Pallas rejections (mesh, non-ELL sparse)
-        share one message text each — PALLAS_MESH_ERROR and
-        PALLAS_SPARSE_ELL_ERROR — with the sparse design's defensive check.
+        solves; the remaining Pallas rejections (mesh, non-ELL sparse,
+        sparse on a TPU) share one message text each — PALLAS_MESH_ERROR,
+        PALLAS_SPARSE_ELL_ERROR (also the sparse design's defensive check)
+        and PALLAS_SPARSE_TPU_ERROR.
         ``weighted=True`` additionally checks the sample-weight leaf is
         runnable (the datafit declares SUPPORTS_WEIGHTS).
         """
@@ -1119,6 +1148,9 @@ class SolveEngine:
                     f"mesh=...: sparse designs cannot be sample-sharded "
                     f"(CSC rows are global); use a (1, k) mesh with the "
                     f"features on the {self.model_axis} axis")
+            if self.config.backend == "pallas" and \
+                    jax.default_backend() == "tpu":
+                raise NotImplementedError(PALLAS_SPARSE_TPU_ERROR)
             if self.mesh is None and self.config.backend == "pallas" and \
                     not getattr(design, "has_ell", False):
                 raise NotImplementedError(PALLAS_SPARSE_ELL_ERROR)

@@ -193,11 +193,19 @@ class L05:
         t = step * self.lam
         a = jnp.abs(x)
         thresh = 1.5 * t ** (2.0 / 3.0)
-        # phi = arccos((t/4) * (a/3)^{-3/2}); guard the zero region against nan.
-        safe_a = jnp.maximum(a, thresh + 1e-30)
-        phi = jnp.arccos(jnp.clip(0.25 * t * (safe_a / 3.0) ** (-1.5), -1.0, 1.0))
-        z = (2.0 / 3.0) * safe_a * (1.0 + jnp.cos(2.0 * jnp.pi / 3.0 - 2.0 * phi / 3.0))
-        return jnp.where(a <= thresh, 0.0, jnp.sign(x) * z)
+        # Outside the dead zone z = u^2, u the largest root of
+        # h(u) = u^3 - a u + t/2 (stationarity of 0.5 (z - a)^2 + t sqrt(z)).
+        # h is convex and increasing right of that root and h(sqrt(a)) = t/2
+        # > 0, so Newton from u0 = sqrt(a) descends monotonically onto it.
+        # (Same root as the arccos form of Xu et al. 2012, but built from
+        # ops the TPU kernel compiler lowers.)
+        def newton(_, u):
+            hp = 3.0 * u * u - a
+            return u - (u * u * u - a * u + 0.5 * t) / jnp.where(hp > 0.0, hp,
+                                                                 1.0)
+
+        u = jax.lax.fori_loop(0, 30, newton, jnp.sqrt(a))
+        return jnp.where(a <= thresh, 0.0, jnp.sign(x) * u * u)
 
     def subdiff_dist(self, grad, beta):
         # Only meaningful away from 0: |grad + lam * sign(beta)/(2 sqrt|beta|)|.
@@ -230,15 +238,16 @@ class L23:
         t = step * self.lam
         a = jnp.abs(x)
         a_safe = jnp.maximum(a, 1e-30)
-        u = jnp.cbrt(a_safe)                      # largest-root init
+        # a^(1/3) as a power: the TPU kernel compiler has no cbrt
+        u_max = a_safe ** (1.0 / 3.0)              # largest-root init
 
-        def newton(u, _):
+        def newton(_, u):
             h = u ** 4 - a_safe * u + (2.0 / 3.0) * t
             hp = 4.0 * u ** 3 - a_safe
             u = u - h / jnp.where(jnp.abs(hp) > 1e-30, hp, 1e-30)
-            return jnp.clip(u, 0.0, jnp.cbrt(a_safe)), None
+            return jnp.clip(u, 0.0, u_max)
 
-        u, _ = jax.lax.scan(newton, u, None, length=40)
+        u = jax.lax.fori_loop(0, 40, newton, u_max)
         z = u ** 3
         # exact global choice: objective at the stationary point vs at 0
         obj_z = 0.5 * (z - a) ** 2 + t * z ** (2.0 / 3.0)
@@ -251,7 +260,7 @@ class L23:
     def subdiff_dist(self, grad, beta):
         a = jnp.abs(beta)
         away = jnp.abs(grad + self.lam * (2.0 / 3.0) * jnp.sign(beta)
-                       / jnp.cbrt(jnp.maximum(a, 1e-30)))
+                       / jnp.maximum(a, 1e-30) ** (1.0 / 3.0))
         return jnp.where(beta == 0.0, 0.0, away)
 
     def generalized_support(self, beta):

@@ -253,7 +253,9 @@ def solve(X, y, datafit, penalty, *, tol=1e-6, max_outer=50, max_epochs=1000,
     >>> res.converged, res.beta.shape
     (True, (p,))
     """
-    design = as_design(X)
+    # a mesh solve places a host array straight onto its shards
+    design = as_design(X, on_host=(mesh if engine is None
+                                   else engine.mesh) is not None)
     n_rows, p = design.shape
     if not use_ws:
         p0 = p

@@ -4,17 +4,27 @@ TPU adaptation (DESIGN.md §2): the sequential per-coordinate updates run with
 *all mutable state resident in VMEM* across the whole epoch block, so each
 update is a VPU-width vector op with zero HBM round-trips:
 
-  * cd_epoch_gram_kernel: state (beta, q = G beta) stays in VMEM; the Gram
-    columns are streamed HBM->VMEM one per grid step, (K, 1) at a time.
-    Each coordinate update is an O(K) axpy.
-  * cd_epoch_xb_kernel: state (beta, Xb) stays in VMEM; working-set columns
-    of X stream (1, n) per grid step. Each update is an O(n) dot + axpy.
+  * cd_epoch_gram_kernel: state (beta, q = G beta) stays in VMEM as
+    lane-major [1, K] rows; G streams HBM->VMEM eight rows per grid step
+    (G is symmetric, so row i is column i). Each update is an O(K) axpy.
+  * cd_epoch_xb_kernel: state (beta [1, K], Xb [1, n]) stays in VMEM; the
+    working-set columns of X (rows of X_ws^T) stream eight per grid step.
+    Each update is an O(n) dot + axpy.
 
-Grid = (epochs, K): one kernel launch runs a full M-epoch block of the inner
-solver. Penalty hyper-parameters live in a small parameter vector, so one
-compiled kernel serves a whole regularization path.
+Every block is (8, ·) rows by a full-width lane axis, the tiling Mosaic
+accepts for float32. The coordinate inside a block is a static unrolled
+index; its scalars (beta_j, q_j, L_j, ...) are read with a one-lane masked
+sum, which is exact (one term plus zeros), and written back with a lane
+select — no dynamic scalar indexing into vector memory. K is padded to a
+multiple of 8 with inert coordinates (L_j = 0 leaves beta_j and the state
+untouched).
 
-Validated on CPU with interpret=True against repro/kernels/ref.py.
+Grid = (epochs, K / 8): one kernel launch runs a full M-epoch block of the
+inner solver. Penalty hyper-parameters live in SMEM, so one compiled kernel
+serves a whole regularization path.
+
+Validated on CPU with interpret=True against repro/kernels/ref.py, and
+compiled for a described TPU v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -23,106 +33,134 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import check_kernel_penalty, make_penalty, pid
+from .common import check_kernel_penalty, make_penalty
+
+ROWS = 8                      # sublanes per float32 tile: rows per grid step
 
 
-def _cd_gram_kernel(penalty_cls, G_col, c_ref, L_ref, params, beta0, q0,
-                    beta_ref, q_ref):
-    e = pid(0)
-    i = pid(1)
+def _pad_to(v, K, axis=0):
+    """Zero-pad `v` along `axis` to length K (no-op when already K)."""
+    extra = K - v.shape[axis]
+    if not extra:
+        return v
+    widths = [(0, 0)] * v.ndim
+    widths[axis] = (0, extra)
+    return jnp.pad(v, widths)
 
-    @pl.when((e == 0) & (i == 0))
-    def _init():
-        beta_ref[:, :] = beta0[:, :]
-        q_ref[:, :] = q0[:, :]
 
-    pen = make_penalty(penalty_cls, params[0], beta_ref.dtype)
-    Lj = L_ref[i, 0]
+def _pick(at, v):
+    """v[j] for the one-lane mask `at` as a [1, 1] array (exact: one term
+    plus zeros)."""
+    return jnp.sum(jnp.where(at, v, 0.0), axis=1, keepdims=True)
+
+
+def _coord_update(pen, at, beta, L, gj):
+    """One prox step on the coordinate selected by the lane mask `at`.
+    Returns (delta [1, 1], beta with that coordinate replaced)."""
+    Lj, bj = _pick(at, L), _pick(at, beta)
     step = 1.0 / jnp.maximum(Lj, 1e-30)
-    bj = beta_ref[i, 0]
-    gj = q_ref[i, 0] - c_ref[i, 0]
-    new = pen.prox(bj - gj * step, step)
-    new = jnp.where(Lj > 0.0, new, bj)
-    delta = new - bj
-    q_ref[:, :] = q_ref[:, :] + delta * G_col[:, :]
-    pl.store(beta_ref, (pl.ds(i, 1), slice(None)), jnp.full((1, 1), new, beta_ref.dtype))
+    new = jnp.where(Lj > 0.0, pen.prox(bj - gj * step, step), bj)
+    return new - bj, jnp.where(at, new, beta)
+
+
+def _cd_gram_kernel(penalty_cls, G_rows, c_ref, L_ref, params, beta0, q0,
+                    beta_ref, q_ref):
+    e, b = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((e == 0) & (b == 0))
+    def _init():
+        beta_ref[...] = beta0[...]
+        q_ref[...] = q0[...]
+
+    pen = make_penalty(penalty_cls, params, beta_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, beta_ref.shape, 1)
+    c, L = c_ref[...], L_ref[...]
+    beta, q = beta_ref[...], q_ref[...]
+    for r in range(ROWS):
+        at = lane == b * ROWS + r
+        delta, beta = _coord_update(pen, at, beta, L,
+                                    _pick(at, q) - _pick(at, c))
+        q = q + delta * G_rows[r:r + 1, :]
+    beta_ref[...] = beta
+    q_ref[...] = q
 
 
 def cd_epoch_gram_pallas(G, c, beta0, q0, L, penalty_cls, params, *, epochs=1,
                          interpret=True):
     """Run `epochs` cyclic CD epochs on the Gram subproblem.
 
-    G: [K, K]; c, beta0, q0, L: [K]. Returns (beta, q), both [K].
+    G: [K, K] (symmetric); c, beta0, q0, L: [K]. Returns (beta, q), both [K].
     """
     check_kernel_penalty(penalty_cls)
     K = G.shape[0]
-    W = params.shape[-1]                        # codec arity for penalty_cls
-    col = lambda e, i: (0, i)
-    const = lambda e, i: (0, 0)
+    Kp = -(-K // ROWS) * ROWS
+    rows = lambda e, b: (b, 0)
+    const = lambda e, b: (0, 0)
+    vec = pl.BlockSpec((1, Kp), const)
+    row = lambda v: _pad_to(v, Kp)[None, :]
     beta, q = pl.pallas_call(
         functools.partial(_cd_gram_kernel, penalty_cls),
-        grid=(epochs, K),
+        grid=(epochs, Kp // ROWS),
         in_specs=[
-            pl.BlockSpec((K, 1), col),          # streamed Gram column
-            pl.BlockSpec((K, 1), const),        # c
-            pl.BlockSpec((K, 1), const),        # L
-            pl.BlockSpec((1, W), const),        # penalty params
-            pl.BlockSpec((K, 1), const),        # beta0
-            pl.BlockSpec((K, 1), const),        # q0
+            pl.BlockSpec((ROWS, Kp), rows),                 # streamed G rows
+            vec,                                            # c
+            vec,                                            # L
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # penalty params
+            vec,                                            # beta0
+            vec,                                            # q0
         ],
-        out_specs=[pl.BlockSpec((K, 1), const), pl.BlockSpec((K, 1), const)],
-        out_shape=[jax.ShapeDtypeStruct((K, 1), G.dtype),
-                   jax.ShapeDtypeStruct((K, 1), G.dtype)],
+        out_specs=[vec, vec],
+        out_shape=[jax.ShapeDtypeStruct((1, Kp), G.dtype)] * 2,
         interpret=interpret,
-    )(G, c[:, None], L[:, None], params[None, :].astype(G.dtype),
-      beta0[:, None], q0[:, None])
-    return beta[:, 0], q[:, 0]
+    )(_pad_to(_pad_to(G, Kp, 0), Kp, 1), row(c), row(L),
+      params.astype(G.dtype), row(beta0), row(q0))
+    return beta[0, :K], q[0, :K]
 
 
 def _cd_xb_kernel(penalty_cls, datafit_kind, n_samples, has_w, *refs):
     if has_w:
-        (x_row, y_ref, w_ref, off_ref, L_ref, params, beta0, Xb0, beta_ref,
+        (x_rows, y_ref, w_ref, off_ref, L_ref, params, beta0, Xb0, beta_ref,
          Xb_ref) = refs
     else:
-        (x_row, y_ref, off_ref, L_ref, params, beta0, Xb0, beta_ref,
+        (x_rows, y_ref, off_ref, L_ref, params, beta0, Xb0, beta_ref,
          Xb_ref) = refs
-    e = pid(0)
-    i = pid(1)
+    e, b = pl.program_id(0), pl.program_id(1)
 
-    @pl.when((e == 0) & (i == 0))
+    @pl.when((e == 0) & (b == 0))
     def _init():
-        beta_ref[:, :] = beta0[:, :]
-        Xb_ref[:, :] = Xb0[:, :]
+        beta_ref[...] = beta0[...]
+        Xb_ref[...] = Xb0[...]
 
-    pen = make_penalty(penalty_cls, params[0], beta_ref.dtype)
-    Xb = Xb_ref[:, :]
+    pen = make_penalty(penalty_cls, params, beta_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, beta_ref.shape, 1)
+    y = y_ref[...]
+    off, L = off_ref[...], L_ref[...]
+    beta, Xb = beta_ref[...], Xb_ref[...]
     # weighted raw-gradient formulas match repro.core.datafits (sum(w) = n
     # normalization is the caller's contract)
     if datafit_kind == "quadratic":
-        raw = (Xb - y_ref[:, :]) / n_samples
-        if has_w:
-            raw = w_ref[:, :] * raw
+        raw_grad = lambda Xb: (Xb - y) / n_samples
     elif datafit_kind == "logistic":
-        y = y_ref[:, :]
-        raw = -y * jax.nn.sigmoid(-y * Xb) / n_samples
-        if has_w:
-            raw = w_ref[:, :] * raw
+        raw_grad = lambda Xb: -y * jax.nn.sigmoid(-y * Xb) / n_samples
     elif datafit_kind == "svc":
         if has_w:
             raise ValueError("QuadraticSVC does not support sample weights")
-        raw = Xb
+        raw_grad = lambda Xb: Xb
     else:
         raise ValueError(datafit_kind)
-    xj = x_row[:, :]                               # (1, n)
-    gj = jnp.sum(xj * raw) + off_ref[i, 0]
-    Lj = L_ref[i, 0]
-    step = 1.0 / jnp.maximum(Lj, 1e-30)
-    bj = beta_ref[i, 0]
-    new = pen.prox(bj - gj * step, step)
-    new = jnp.where(Lj > 0.0, new, bj)
-    Xb_ref[:, :] = Xb + (new - bj) * xj
-    pl.store(beta_ref, (pl.ds(i, 1), slice(None)), jnp.full((1, 1), new, beta_ref.dtype))
+    for r in range(ROWS):
+        raw = raw_grad(Xb)
+        if has_w:
+            raw = w_ref[...] * raw
+        at = lane == b * ROWS + r
+        xj = x_rows[r:r + 1, :]                            # (1, n)
+        gj = jnp.sum(xj * raw, axis=1, keepdims=True) + _pick(at, off)
+        delta, beta = _coord_update(pen, at, beta, L, gj)
+        Xb = Xb + delta * xj
+    beta_ref[...] = beta
+    Xb_ref[...] = Xb
 
 
 def cd_epoch_xb_pallas(Xt_ws, y, beta0, Xb0, L, offset, penalty_cls, params,
@@ -136,37 +174,32 @@ def cd_epoch_xb_pallas(Xt_ws, y, beta0, Xb0, L, offset, penalty_cls, params,
     """
     check_kernel_penalty(penalty_cls)
     K, n = Xt_ws.shape
-    W = params.shape[-1]                        # codec arity for penalty_cls
+    Kp = -(-K // ROWS) * ROWS
     has_w = w is not None
-    row = lambda e, i: (i, 0)
-    const = lambda e, i: (0, 0)
+    const = lambda e, b: (0, 0)
+    kvec = pl.BlockSpec((1, Kp), const)
+    nvec = pl.BlockSpec((1, n), const)
+    row = lambda v: _pad_to(v, Kp)[None, :]
     kern = functools.partial(_cd_xb_kernel, penalty_cls, datafit_kind, n,
                              has_w)
-    in_specs = [
-        pl.BlockSpec((1, n), row),          # streamed X_ws column (as row)
-        pl.BlockSpec((1, n), const),        # y
-    ]
-    operands = [Xt_ws, y[None, :]]
+    in_specs = [pl.BlockSpec((ROWS, n), lambda e, b: (b, 0)),  # X_ws^T rows
+                nvec]                                          # y
+    operands = [_pad_to(Xt_ws, Kp), y[None, :]]
     if has_w:
-        in_specs.append(pl.BlockSpec((1, n), const))   # sample weights
+        in_specs.append(nvec)                                  # weights
         operands.append(w[None, :])
-    in_specs += [
-        pl.BlockSpec((K, 1), const),        # grad offset
-        pl.BlockSpec((K, 1), const),        # L
-        pl.BlockSpec((1, W), const),        # penalty params
-        pl.BlockSpec((K, 1), const),        # beta0
-        pl.BlockSpec((1, n), const),        # Xb0
-    ]
-    operands += [offset[:, None], L[:, None],
-                 params[None, :].astype(Xt_ws.dtype), beta0[:, None],
-                 Xb0[None, :]]
+    in_specs += [kvec, kvec,                                   # offset, L
+                 pl.BlockSpec(memory_space=pltpu.SMEM),        # params
+                 kvec, nvec]                                   # beta0, Xb0
+    operands += [row(offset), row(L), params.astype(Xt_ws.dtype),
+                 row(beta0), Xb0[None, :]]
     beta, Xb = pl.pallas_call(
         kern,
-        grid=(epochs, K),
+        grid=(epochs, Kp // ROWS),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((K, 1), const), pl.BlockSpec((1, n), const)],
-        out_shape=[jax.ShapeDtypeStruct((K, 1), Xt_ws.dtype),
+        out_specs=[kvec, nvec],
+        out_shape=[jax.ShapeDtypeStruct((1, Kp), Xt_ws.dtype),
                    jax.ShapeDtypeStruct((1, n), Xt_ws.dtype)],
         interpret=interpret,
     )(*operands)
-    return beta[:, 0], Xb[0]
+    return beta[0, :K], Xb[0]

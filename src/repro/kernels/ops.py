@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute with interpret=True (the kernel body
-runs in Python/XLA-CPU); on a real TPU set interpret=False (the default picks
-automatically from the backend).
+Off a TPU the kernels execute with interpret=True (the kernel body runs as
+XLA-CPU ops); on a TPU they compile natively. ``interpret=None`` picks from
+the backend.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Production mesh construction + shard_map version compatibility.
+"""Production mesh construction.
 
 Functions (not module-level constants) so importing this module never
 touches jax device state. The dry-run sets
@@ -6,35 +6,12 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import.
 """
 from __future__ import annotations
 
-import inspect as _inspect
-
 import jax
-
-try:
-    from jax import shard_map as _jax_shard_map
-except ImportError:                      # jax < 0.5: experimental namespace
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-_HAS_CHECK_VMA = "check_vma" in _inspect.signature(_jax_shard_map).parameters
-
-
-def shard_map(f, **kw):
-    """shard_map with the `check_vma` kwarg mapped to pre-0.5 `check_rep`.
-
-    The canonical shim for the whole repo (the mesh-native solve engine in
-    core/engine.py and the core/distributed.py facade both import it)."""
-    if "check_vma" in kw and not _HAS_CHECK_VMA:
-        kw["check_rep"] = kw.pop("check_vma")
-    return _jax_shard_map(f, **kw)
 
 
 def _mesh(shape, axes):
-    """jax<0.5 has no sharding.AxisType / make_mesh(axis_types=...)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -10,13 +10,11 @@ enforces in CI — see DESIGN.md §10).
     PYTHONPATH=src python -m repro.roofline.analyze --n 128 --p 1024 --ws 64
     PYTHONPATH=src python -m repro.roofline.analyze --check-ratio 0.6
 """
+import argparse
+import json
 import os
-if "JAX_PLATFORMS" not in os.environ:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import argparse      # noqa: E402
-import json          # noqa: E402
 
-import jax           # noqa: E402
+import jax
 
 jax.config.update("jax_enable_x64", True)
 

@@ -67,12 +67,12 @@ def fused_bytes_model(n: int, p: int, ws: int, itemsize: int = 8,
     (no X traffic). Returns per-stage bytes ('kernel', 'select',
     'recover') plus their 'total'."""
     R = max(n_tasks, 1)
-    bp = _pick_bp(p) if bp is None else bp
+    bp = _pick_bp(n, p, ws, R, itemsize) if bp is None else bp
     tiles = -(-p // bp)
     p_pad = tiles * bp
     kc = min(bp, ws)
     C = tiles * kc
-    kernel = ((n * p_pad                    # X tiles, each read once
+    kernel = ((n * p                        # X tiles, each read once
                + n * R                      # raw gradient (revolving block)
                + p_pad * R                  # beta
                + 3 * p_pad                  # L, offset, gsupp
@@ -191,7 +191,7 @@ def stage_table(n: int, p: int, ws: int, dtype=jnp.float64,
         "shape": {"n": n, "p": p, "ws": ws, "itemsize": itemsize,
                   "n_tasks": n_tasks,
                   "gather_granularity": GATHER_GRANULARITY,
-                  "bp": _pick_bp(p)},
+                  "bp": _pick_bp(n, p, ws, max(n_tasks, 1), itemsize)},
         "stages": {},
         "two_pass_bytes_model": two,
         "fused_bytes_model": fused,

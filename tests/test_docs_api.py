@@ -153,6 +153,28 @@ def test_remaining_rejections_raise_at_entry():
     assert "supported-path matrix" in PALLAS_SPARSE_ELL_ERROR
 
 
+def test_sparse_pallas_rejected_at_entry_on_tpu(monkeypatch):
+    """On a TPU the ELL score kernel does not compile: a sparse solve on the
+    Pallas backend raises PALLAS_SPARSE_TPU_ERROR at entry (ELL layout or
+    not) instead of falling back to the jax path in silence."""
+    import jax
+    import scipy.sparse as sp
+    from repro.core import L1, Quadratic, make_engine, solve
+    from repro.core.engine import PALLAS_SPARSE_TPU_ERROR
+    from repro.sparse import CSCDesign
+
+    Xs = sp.random(20, 32, density=0.2, random_state=0, format="csc")
+    y = jnp.asarray(np.random.default_rng(0).standard_normal(20))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for X in (Xs, CSCDesign.from_scipy(Xs, ell=True)):
+        eng = make_engine(L1(0.1), Quadratic(), use_kernels=True)
+        with pytest.raises(NotImplementedError) as ei:
+            solve(X, y, Quadratic(), L1(0.1), engine=eng)
+        assert str(ei.value) == PALLAS_SPARSE_TPU_ERROR
+        assert eng.n_dispatches == 0
+    assert "supported-path matrix" in PALLAS_SPARSE_TPU_ERROR
+
+
 def test_reg_path_rejects_at_entry_both_drivers():
     """Both path drivers raise the same entry error (the chunked driver
     never reaches solve())."""

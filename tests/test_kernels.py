@@ -36,7 +36,7 @@ def _gram_inputs(K, dtype, seed=0):
 
 
 @pytest.mark.parametrize("penalty", PENALTIES, ids=IDS)
-@pytest.mark.parametrize("K", [8, 64, 200])
+@pytest.mark.parametrize("K", [8, 13, 64, 200])
 @pytest.mark.parametrize("dtype", ["float32", "float64"])
 def test_cd_epoch_gram_matches_ref(penalty, K, dtype):
     G, c, beta0, q0, L = _gram_inputs(K, dtype)
@@ -55,7 +55,7 @@ def test_cd_epoch_gram_matches_ref(penalty, K, dtype):
 @pytest.mark.parametrize("datafit,kind", [
     (Quadratic(), "quadratic"), (Logistic(), "logistic"),
     (QuadraticSVC(), "svc")], ids=["quad", "logistic", "svc"])
-@pytest.mark.parametrize("K,n", [(16, 48), (96, 128)])
+@pytest.mark.parametrize("K,n", [(13, 40), (16, 48), (96, 128)])
 def test_cd_epoch_xb_matches_ref(penalty, datafit, kind, K, n):
     dtype = "float64"
     rng = np.random.default_rng(1)

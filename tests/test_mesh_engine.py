@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 from repro.core import (MCP, L1, BlockL1, BlockMCP, Box, Logistic,
                         MultitaskQuadratic, Quadratic, QuadraticSVC,
@@ -44,7 +45,7 @@ from repro.core.engine import EngineConfig, get_engine
 from repro.core.estimators import Lasso
 from repro.data.synth import (make_classification, make_correlated_design,
                               make_multitask)
-from repro.launch.mesh import make_solver_mesh, make_test_mesh, shard_map
+from repro.launch.mesh import make_solver_mesh, make_test_mesh
 
 requires8 = pytest.mark.skipif(
     len(jax.devices()) < 8,

@@ -1,0 +1,96 @@
+"""Ahead-of-time compiles of the main-path kernels for a TPU v5e.
+
+The TPU compiler is installed without a chip: it compiles for a *described*
+v5e and raises what the chip's compiler would raise — unaligned blocks, a
+kernel over its scoped VMEM, a primitive Mosaic cannot lower — none of
+which interpret mode can see. Compiles run in float32 (x64 off, as on the
+chip) at the widths ``chip_smoke.py`` drives: the fused working-set head at
+n=10,000, p=20,000, ws=256; the Gram CD epoch at K=1024 for every penalty
+the kernel codec accepts; the Xb CD epoch at K=256, n=10,000 for the
+quadratic and logistic datafits. Each compiled program must hold a Mosaic
+kernel (``tpu_custom_call``), i.e. nothing fell back to interpret mode.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and test workers all import
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.common import SCALAR_COORD_PENALTIES, penalty_arity
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no libtpu, or the library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compile_f32():
+    """Compile in float32 with the persistent cache off: a compile for a
+    described chip is written to the cache but cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def run(fn, *args, **static):
+        with jax.enable_x64(False):
+            return fn.lower(*args, **static).compile().as_text()
+    try:
+        yield run
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _spec(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def test_fused_head_compiles_for_v5e(one_chip, compile_f32):
+    from repro.core.penalties import L1
+    n, p, ws = 10_000, 20_000, 256
+    s = lambda *shape: _spec(one_chip, *shape)
+    text = compile_f32(ops.fused_ws, s(n, p), s(n), s(p), s(p), s(p), s(p),
+                       L1, s(penalty_arity(L1)), ws, interpret=False)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "penalty_cls", sorted(SCALAR_COORD_PENALTIES, key=lambda c: c.__name__),
+    ids=lambda c: c.__name__)
+def test_gram_epoch_compiles_for_v5e(one_chip, compile_f32, penalty_cls):
+    K = 1024
+    s = lambda *shape: _spec(one_chip, *shape)
+    text = compile_f32(ops.cd_epoch_gram, s(K, K), s(K), s(K), s(K), s(K),
+                       penalty_cls, s(penalty_arity(penalty_cls)),
+                       interpret=False)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind", ["quadratic", "logistic"])
+def test_xb_epoch_compiles_for_v5e(one_chip, compile_f32, kind):
+    from repro.core.penalties import L1
+    K, n = 256, 10_000
+    s = lambda *shape: _spec(one_chip, *shape)
+    text = compile_f32(ops.cd_epoch_xb, s(K, n), s(n), s(K), s(n), s(K),
+                       s(K), L1, s(penalty_arity(L1)), kind,
+                       interpret=False)
+    assert "tpu_custom_call" in text
