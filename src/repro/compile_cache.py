@@ -19,14 +19,23 @@ def enable_compile_cache() -> str:
     """Turn the persistent cache on and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it by itself and
-    this sets nothing. Otherwise the cache goes to ``<checkout>/.jax-cache``,
-    a fixed path (found from this file), so a second run in the same
-    checkout finds the first run's compiles.
+    this sets no directory. Otherwise the cache goes to
+    ``<checkout>/.jax-cache``, a fixed path (found from this file), so a
+    second run in the same checkout finds the first run's compiles.
+
+    The cache key includes the ops' names (``op_name``: the jit, scope and
+    primitive of each op): by default JAX leaves metadata out, so a program
+    whose stage scopes differ would read another program's executable, and
+    a profile would show the other program's op names. Source frames are
+    left out of the metadata, so the key does not move with a source line,
+    a caller or the checkout's path.
     """
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     path = str(CHECKOUT / ".jax-cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

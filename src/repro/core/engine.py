@@ -695,6 +695,12 @@ class SolveEngine:
                    tol, eps_frac, bucket, obs=None):
         """Fused: score -> select -> gather -> inner solve -> scatter.
 
+        Each stage runs under a ``jax.named_scope`` of that name (``score``,
+        ``select``, ``gather``, ``inner_solve``, ``scatter``; the ring record
+        under ``telemetry``), so every op of the compiled step names its
+        stage in its ``op_name`` metadata and a device trace's ops can be
+        put down to stages (DESIGN.md §11.2). Scopes change no op.
+
         On a mesh: local views design [n_loc, width], y/w/Xb [n_loc],
         beta/L/offset [width]; working-set indices are global; the K-sized
         subproblem runs replicated over the whole mesh (Gram form) or keeps
@@ -731,38 +737,42 @@ class SolveEngine:
             from repro.kernels.common import penalty_params
             fp = (not penalty.HAS_SUBDIFF) if cfg.use_fp_score is None \
                 else cfg.use_fp_score
-            raw = _df_raw(datafit, Xb, y, w)
-            gsupp = penalty.generalized_support(beta)
-            scores, grad, cand_idx, cand_cols = kops.fused_ws(
-                design.X, raw, beta, L, offset,
-                gsupp.astype(design.X.dtype), type(penalty),
-                penalty_params(penalty), bucket, use_fp=fp)
-            kkt = jnp.max(scores)
-            gcount0 = jnp.sum(gsupp, dtype=jnp.int32)
-            obj = _df_value(datafit, Xb, y, w) + _lin(offset, beta) + \
-                penalty.value(beta)
-            ws = select_working_set(scores, gsupp, bucket)
+            with jax.named_scope("score"):
+                raw = _df_raw(datafit, Xb, y, w)
+                gsupp = penalty.generalized_support(beta)
+                scores, grad, cand_idx, cand_cols = kops.fused_ws(
+                    design.X, raw, beta, L, offset,
+                    gsupp.astype(design.X.dtype), type(penalty),
+                    penalty_params(penalty), bucket, use_fp=fp)
+                kkt = jnp.max(scores)
+                gcount0 = jnp.sum(gsupp, dtype=jnp.int32)
+                obj = _df_value(datafit, Xb, y, w) + _lin(offset, beta) + \
+                    penalty.value(beta)
+            with jax.named_scope("select"):
+                ws = select_working_set(scores, gsupp, bucket)
             mine, loc = None, ws
-            X_ws = candidate_columns(cand_idx, cand_cols, ws, width)
+            with jax.named_scope("gather"):
+                X_ws = candidate_columns(cand_idx, cand_cols, ws, width)
             sdf, ws_aux = None, None
         else:
-            sdf, grad, scores, kkt, gsupp, gcount0, obj = self._score_pass(
-                design, y, w, beta, Xb, L, offset, datafit, penalty)
-            ws = select_working_set_local(scores, gsupp, bucket, ma)
-            mine, loc = shard_ws_mask(ws, width, ma)
-            # [n_loc, K] model-replicated ws columns (+ sparse windows)
-            X_ws, ws_aux = design.gather_ws(mine, loc, ma)
-        L_ws = gather_ws_vec(L, mine, loc, ma)
-        offset_ws = gather_ws_vec(offset, mine, loc, ma)
-        beta_ws0 = gather_ws_vec(beta, mine, loc, ma)
-        grad_ws0 = gather_ws_vec(grad, mine, loc, ma)
-        in_ws = gsupp[loc] if mine is None else jnp.where(mine, gsupp[loc],
-                                                          False)
-        cov = _psum_if(jnp.sum(in_ws, dtype=jnp.int32), ma) == gcount0
-        pen_ws = penalty.restricted(ws) if hasattr(penalty, "restricted") \
-            else penalty
-        eps_in = jnp.maximum(eps_frac * kkt, 0.1 * tol)
-        done = kkt <= tol
+            with jax.named_scope("score"):
+                sdf, grad, scores, kkt, gsupp, gcount0, obj = \
+                    self._score_pass(design, y, w, beta, Xb, L, offset,
+                                     datafit, penalty)
+            with jax.named_scope("select"):
+                ws = select_working_set_local(scores, gsupp, bucket, ma)
+                mine, loc = shard_ws_mask(ws, width, ma)
+            with jax.named_scope("gather"):
+                # [n_loc, K] model-replicated ws columns (+ sparse windows)
+                X_ws, ws_aux = design.gather_ws(mine, loc, ma)
+        with jax.named_scope("gather"):
+            L_ws = gather_ws_vec(L, mine, loc, ma)
+            offset_ws = gather_ws_vec(offset, mine, loc, ma)
+            beta_ws0 = gather_ws_vec(beta, mine, loc, ma)
+            grad_ws0 = gather_ws_vec(grad, mine, loc, ma)
+            in_ws = gsupp[loc] if mine is None \
+                else jnp.where(mine, gsupp[loc], False)
+            cov = _psum_if(jnp.sum(in_ws, dtype=jnp.int32), ma) == gcount0
         inner = self._make_inner()
         track = obs is not None           # count Anderson accepts for the ring
         # the pass-through sdf wrapper would break the pallas kernels'
@@ -770,71 +780,80 @@ class SolveEngine:
         # whenever the samples are unsplit
         ctx_df = datafit if da is None else sdf
 
-        if cfg.gram:
-            if da is None:
-                # samples unsplit: honor the datafit's own make_gram (c is
-                # discarded — it assumes support ⊆ ws; see linearization)
-                G, _ = datafit.make_gram(X_ws, y) if w is None \
-                    else datafit.make_gram(X_ws, y, w)
+        with jax.named_scope("inner_solve"):
+            pen_ws = penalty.restricted(ws) \
+                if hasattr(penalty, "restricted") else penalty
+            eps_in = jnp.maximum(eps_frac * kkt, 0.1 * tol)
+            done = kkt <= tol
+            if cfg.gram:
+                if da is None:
+                    # samples unsplit: honor the datafit's own make_gram (c
+                    # is discarded — it assumes support ⊆ ws; see
+                    # linearization)
+                    G, _ = datafit.make_gram(X_ws, y) if w is None \
+                        else datafit.make_gram(X_ws, y, w)
+                else:
+                    # exact distributed Gram: one sharded MXU matmul + psum;
+                    # the K x K subproblem and its Anderson-CD run
+                    # replicated (weights enter as X_ws^T diag(w) X_ws on
+                    # the local rows)
+                    Xw = X_ws if w is None else w[:, None] * X_ws
+                    G = jax.lax.psum(X_ws.T @ Xw, da)
+                    if sdf.sample_mean:
+                        G = G / n_glob
+                # linearize at the incoming iterate: grad_ws(b) = G (b - b0)
+                # + grad0_ws, exact for quadratic datafits even when nonzero
+                # coordinates live outside ws (Box pins coords at C with
+                # empty generalized support)
+                q0 = G @ beta_ws0
+                c = q0 - grad_ws0
+                ctx = WorkingSetContext(X_ws.T, y, L_ws, offset_ws, ctx_df,
+                                        pen_ws, G=G, c=c)
+
+                def run(_):
+                    beta_ws, _, n_ep, _, n_acc = inner.solve(
+                        ctx, beta_ws0, eps_in, aux0=q0, track=track)
+                    return beta_ws, n_ep, n_acc
+
+                def skip(_):
+                    zero = jnp.zeros((), jnp.int32)
+                    return beta_ws0, zero, (zero if track else None)
+
+                beta_ws, n_ep, n_acc = jax.lax.cond(done, skip, run, None)
             else:
-                # exact distributed Gram: one sharded MXU matmul + psum; the
-                # K x K subproblem and its Anderson-CD run replicated
-                # (weights enter as X_ws^T diag(w) X_ws on the local rows)
-                Xw = X_ws if w is None else w[:, None] * X_ws
-                G = jax.lax.psum(X_ws.T @ Xw, da)
-                if sdf.sample_mean:
-                    G = G / n_glob
-            # linearize at the incoming iterate: grad_ws(b) = G (b - b0) +
-            # grad0_ws, exact for quadratic datafits even when nonzero
-            # coordinates live outside ws (Box pins coords at C with empty
-            # generalized support)
-            q0 = G @ beta_ws0
-            c = q0 - grad_ws0
-            ctx = WorkingSetContext(X_ws.T, y, L_ws, offset_ws, ctx_df,
-                                    pen_ws, G=G, c=c)
+                # Xb form: rows stay data-sharded; each coordinate update's
+                # n-reduction is completed with one psum over the data axis.
+                # Xb_base carries the residual of nonzero coordinates
+                # OUTSIDE ws so Anderson refresh cannot drop them
+                ctx = WorkingSetContext(X_ws.T, y, L_ws, offset_ws, ctx_df,
+                                        pen_ws, axis=da, w=w,
+                                        Xb_base=Xb - _apply_T(X_ws.T,
+                                                              beta_ws0))
 
-            def run(_):
-                beta_ws, _, n_ep, _, n_acc = inner.solve(ctx, beta_ws0,
-                                                         eps_in, aux0=q0,
-                                                         track=track)
-                return beta_ws, n_ep, n_acc
+                def run(_):
+                    # Xb is shared outer-loop state: enter with the
+                    # caller's Xb
+                    beta_ws, Xb2, n_ep, _, n_acc = inner.solve(
+                        ctx, beta_ws0, eps_in, aux0=Xb, track=track)
+                    return beta_ws, Xb2, n_ep, n_acc
 
-            def skip(_):
-                zero = jnp.zeros((), jnp.int32)
-                return beta_ws0, zero, (zero if track else None)
+                def skip(_):
+                    zero = jnp.zeros((), jnp.int32)
+                    return beta_ws0, Xb, zero, (zero if track else None)
 
-            beta_ws, n_ep, n_acc = jax.lax.cond(done, skip, run, None)
-            # incremental residual: exact even when a nonzero coordinate
-            # sits outside ws
-            Xb_new = design.update_xb(Xb, X_ws, ws_aux, beta_ws - beta_ws0,
-                                      ma)
-        else:
-            # Xb form: rows stay data-sharded; each coordinate update's
-            # n-reduction is completed with one psum over the data axis.
-            # Xb_base carries the residual of nonzero coordinates OUTSIDE
-            # ws so Anderson refresh cannot drop them
-            ctx = WorkingSetContext(X_ws.T, y, L_ws, offset_ws, ctx_df,
-                                    pen_ws, axis=da, w=w,
-                                    Xb_base=Xb - _apply_T(X_ws.T, beta_ws0))
+                beta_ws, Xb_new, n_ep, n_acc = jax.lax.cond(done, skip, run,
+                                                            None)
 
-            def run(_):
-                # Xb is shared outer-loop state: enter with the caller's Xb
-                beta_ws, Xb2, n_ep, _, n_acc = inner.solve(ctx, beta_ws0,
-                                                           eps_in, aux0=Xb,
-                                                           track=track)
-                return beta_ws, Xb2, n_ep, n_acc
-
-            def skip(_):
-                zero = jnp.zeros((), jnp.int32)
-                return beta_ws0, Xb, zero, (zero if track else None)
-
-            beta_ws, Xb_new, n_ep, n_acc = jax.lax.cond(done, skip, run,
-                                                        None)
-
-        beta_new = scatter_ws(beta, mine, loc, beta_ws)
-        gcount = _psum_if(
-            jnp.sum(penalty.generalized_support(beta_new), dtype=jnp.int32),
-            ma)
+        with jax.named_scope("scatter"):
+            if cfg.gram:
+                # incremental residual: exact even when a nonzero coordinate
+                # sits outside ws
+                Xb_new = design.update_xb(Xb, X_ws, ws_aux,
+                                          beta_ws - beta_ws0, ma)
+            beta_new = scatter_ws(beta, mine, loc, beta_ws)
+            gcount = _psum_if(
+                jnp.sum(penalty.generalized_support(beta_new),
+                        dtype=jnp.int32), ma)
         if obs is None:
             return beta_new, Xb_new, kkt, obj, gcount, n_ep, cov
         # in-dispatch telemetry (DESIGN.md §11.1): scalars of THIS iteration
@@ -842,16 +861,17 @@ class SolveEngine:
         # the inner solve just run — written at the ring cursor. The gap
         # reuses the residual/gradient the score pass already produced, so
         # recording costs a handful of scalar FLOPs, never an extra pass
-        gap = (quadratic_l1_gap(y, Xb, grad, obj, n_glob, penalty.lam,
-                                da, ma)
-               if gap_supported(datafit, penalty, w)
-               else jnp.full((), jnp.nan, jnp.asarray(obj).dtype))
-        ring = obs.record(
-            kkt=kkt, obj=obj, gap=gap,
-            ws_size=jnp.asarray(bucket, jnp.int32),
-            gsupp=jnp.asarray(gcount0, jnp.int32),
-            epochs=n_ep, accepts=n_acc,
-            occupancy=ws_occupancy(beta_ws))
+        with jax.named_scope("telemetry"):
+            gap = (quadratic_l1_gap(y, Xb, grad, obj, n_glob, penalty.lam,
+                                    da, ma)
+                   if gap_supported(datafit, penalty, w)
+                   else jnp.full((), jnp.nan, jnp.asarray(obj).dtype))
+            ring = obs.record(
+                kkt=kkt, obj=obj, gap=gap,
+                ws_size=jnp.asarray(bucket, jnp.int32),
+                gsupp=jnp.asarray(gcount0, jnp.int32),
+                epochs=n_ep, accepts=n_acc,
+                occupancy=ws_occupancy(beta_ws))
         return beta_new, Xb_new, kkt, obj, gcount, n_ep, cov, ring
 
     def _sharded_step(self, design, y, w, beta, Xb, L, offset, datafit,
@@ -950,21 +970,25 @@ class SolveEngine:
         L_ax = 0 if L.ndim == 2 else None
 
         def cond(state):
-            kkts, gcounts, it = state[2], state[4], state[6]
-            unconverged = kkts > tol
-            live = (it < max_outer) & jnp.any(unconverged)
-            if bucket < p:
-                # hand back to the host for bucket escalation; at bucket == p
-                # the working set already covers every feature
-                outgrown = jnp.any(unconverged & (growth * gcounts > bucket))
-                live = live & ~outgrown
-            return live
+            with jax.named_scope("chunk_control"):
+                kkts, gcounts, it = state[2], state[4], state[6]
+                unconverged = kkts > tol
+                live = (it < max_outer) & jnp.any(unconverged)
+                if bucket < p:
+                    # hand back to the host for bucket escalation; at
+                    # bucket == p the working set already covers every
+                    # feature
+                    outgrown = jnp.any(unconverged
+                                       & (growth * gcounts > bucket))
+                    live = live & ~outgrown
+                return live
 
         C = lams.shape[0]
-        init = (betas, Xbs, jnp.full((C,), jnp.inf, betas.dtype),
-                jnp.zeros((C,), betas.dtype),
-                jnp.zeros((C,), jnp.int32), jnp.zeros((C,), jnp.int32),
-                jnp.zeros((), jnp.int32))
+        with jax.named_scope("chunk_control"):
+            init = (betas, Xbs, jnp.full((C,), jnp.inf, betas.dtype),
+                    jnp.zeros((C,), betas.dtype),
+                    jnp.zeros((C,), jnp.int32), jnp.zeros((C,), jnp.int32),
+                    jnp.zeros((), jnp.int32))
 
         if obs is None:
             def lane(lam, beta, Xb, w_l, L_l):
@@ -976,7 +1000,9 @@ class SolveEngine:
                 betas, Xbs, kkts, objs, gcounts, n_eps, it = state
                 betas, Xbs, kkts, objs, gcounts, d_ep = vstep(lams, betas,
                                                               Xbs, w, L)
-                return betas, Xbs, kkts, objs, gcounts, n_eps + d_ep, it + 1
+                with jax.named_scope("chunk_control"):
+                    return (betas, Xbs, kkts, objs, gcounts, n_eps + d_ep,
+                            it + 1)
 
             return jax.lax.while_loop(cond, body, init)
 
@@ -990,8 +1016,9 @@ class SolveEngine:
             betas, Xbs, kkts, objs, gcounts, n_eps, it, rings = state
             betas, Xbs, kkts, objs, gcounts, d_ep, rings = vstep(
                 lams, betas, Xbs, w, L, rings)
-            return (betas, Xbs, kkts, objs, gcounts, n_eps + d_ep, it + 1,
-                    rings)
+            with jax.named_scope("chunk_control"):
+                return (betas, Xbs, kkts, objs, gcounts, n_eps + d_ep,
+                        it + 1, rings)
 
         return jax.lax.while_loop(cond, body, init + (obs,))
 

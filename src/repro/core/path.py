@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from repro.checkpoint import CheckpointConfig, restore_pytree
-from repro.obs import SolveDiagnostics, TelemetryRing, null_span
+from repro.obs import SolveDiagnostics, TelemetryRing, span
 
 from .api import lambda_max
 from .datafits import Quadratic
@@ -300,7 +300,7 @@ def reg_path(X, y, penalty, datafit=None, *, lambdas=None, n_lambdas=30,
     if engine.mesh is not None:
         design, y, w = _place_design(engine, design, y, w)
 
-    sp = obs.span if obs is not None else null_span
+    sp = obs.span if obs is not None else span
     driver = "chunked" if vmap_chunk > 1 \
         else ("screened" if screen is not None else "sequential")
     with sp("path", driver=driver, n_lambdas=len(lambdas)):
@@ -330,7 +330,7 @@ def _sequential_path(design, y, penalty, datafit, lambdas, tol, engine,
     if screen is not None:
         return _screened_path(design, y, penalty, datafit, lambdas, tol,
                               engine, metric_fn, obs=obs, **solve_kw)
-    sp = obs.span if obs is not None else null_span
+    sp = obs.span if obs is not None else span
     beta = None
     betas, kkts, nnzs, eps, outers, times, metrics = [], [], [], [], [], [], []
     for lam in lambdas:
@@ -365,7 +365,7 @@ def _screened_path(design, y, penalty, datafit, lambdas, tol, engine,
     """
     from .screening import gap_safe_mask_design
 
-    sp = obs.span if obs is not None else null_span
+    sp = obs.span if obs is not None else span
     n, p = design.shape
     beta_full = np.zeros(p)
     betas, kkts, nnzs, eps, outers, times = [], [], [], [], [], []
@@ -423,7 +423,7 @@ def _chunked_path(design, y, penalty, datafit, lambdas, tol, engine, chunk,
         raise ValueError(
             f"vmap_chunk > 1 does not support solve kwargs "
             f"{sorted(unsupported)}; use the sequential driver (vmap_chunk=1)")
-    sp = obs.span if obs is not None else null_span
+    sp = obs.span if obs is not None else span
     use_ring = obs is not None and getattr(obs, "rings", True)
     p = design.shape[1]
     policy = BucketPolicy(p0=p0)
@@ -759,11 +759,14 @@ def cross_val_path(X, y, datafit=None, penalty=None, *, lambdas=None,
         config keys (M, max_epochs, accel, use_fp_score, use_gram,
         use_kernels).
     obs : repro.obs.Obs, optional
-        Observability handle (DESIGN.md §11): grid → lambda_chunk spans on
-        ``obs.tracer`` plus a per-lane telemetry ring through every chunk
-        dispatch — per-outer convergence curves for all (fold, lambda)
-        lanes land on ``GridResult.diagnostics`` as
-        ``[n_folds, n_lambdas, max_outer]`` arrays. Zero extra dispatches.
+        Observability handle (DESIGN.md §11): records the grid's spans
+        (grid → grid_round → grid_lanes, grid_dispatch, grid_sync,
+        grid_retire, grid_bank, grid_fill, grid_schedule; written to any
+        profiler capture with or without ``obs``) on ``obs.tracer``, plus
+        a per-lane telemetry ring through every chunk dispatch — per-outer
+        convergence curves for all (fold, lambda) lanes land on
+        ``GridResult.diagnostics`` as ``[n_folds, n_lambdas, max_outer]``
+        arrays. Zero extra dispatches.
     progress : callable or bool, optional
         Per-round progress events: a callable receives dicts like
         ``{"event": "bucket", "chunk": 1, "n_chunks": 3, "bucket": 64,
@@ -781,381 +784,397 @@ def cross_val_path(X, y, datafit=None, penalty=None, *, lambdas=None,
         Per-fold paths, the CV curve (mean/std held-out loss), the best
         lambda, and engine telemetry.
     """
-    datafit = Quadratic() if datafit is None else datafit
-    penalty = L1(1.0) if penalty is None else penalty
-    design = as_design(X)
-    y = jnp.asarray(y)
-    n, p = design.shape
-    unsupported = set(engine_kw) - set(_ENGINE_KW)
-    if unsupported:
-        raise ValueError(f"cross_val_path does not support kwargs "
-                         f"{sorted(unsupported)}")
+    sp = obs.span if obs is not None else span
+    with sp("grid") as grid_ev:
+        datafit = Quadratic() if datafit is None else datafit
+        penalty = L1(1.0) if penalty is None else penalty
+        design = as_design(X)
+        y = jnp.asarray(y)
+        n, p = design.shape
+        unsupported = set(engine_kw) - set(_ENGINE_KW)
+        if unsupported:
+            raise ValueError(f"cross_val_path does not support kwargs "
+                             f"{sorted(unsupported)}")
 
-    # replicate weights: 0/1 k-fold membership or explicit bootstrap counts
-    if fold_weights is not None:
-        W = np.asarray(fold_weights, np.float64)
-        if W.ndim != 2 or W.shape[1] != n:
+        # replicate weights: 0/1 k-fold membership or explicit bootstrap counts
+        if fold_weights is not None:
+            W = np.asarray(fold_weights, np.float64)
+            if W.ndim != 2 or W.shape[1] != n:
+                raise ValueError(
+                    f"fold_weights must be [n_replicates, n={n}], got shape "
+                    f"{W.shape}")
+            if not np.all(np.isfinite(W)) or np.any(W < 0):
+                raise ValueError(
+                    "fold_weights must be finite and non-negative")
+        else:
+            from repro.data.folds import kfold_weights
+            W = kfold_weights(n, cv, seed=seed)
+        H = np.where(W == 0.0, 1.0, 0.0)          # held-out indicator per fold
+        if sample_weight is not None:
+            sw = np.asarray(
+                normalize_weights(sample_weight, n, jnp.float64))
+            W = W * sw[None, :]
+            H = H * sw[None, :]
+        train_sums = W.sum(axis=1)
+        if np.any(train_sums <= 0):
+            raise ValueError("every fold/replicate needs at least one "
+                             "training sample with positive weight")
+        held_sums = H.sum(axis=1)
+        valid_fold = held_sums > 0
+        if not valid_fold.any():
             raise ValueError(
-                f"fold_weights must be [n_replicates, n={n}], got shape "
-                f"{W.shape}")
-        if not np.all(np.isfinite(W)) or np.any(W < 0):
-            raise ValueError("fold_weights must be finite and non-negative")
-    else:
-        from repro.data.folds import kfold_weights
-        W = kfold_weights(n, cv, seed=seed)
-    H = np.where(W == 0.0, 1.0, 0.0)          # held-out indicator per fold
-    if sample_weight is not None:
-        sw = np.asarray(
-            normalize_weights(sample_weight, n, jnp.float64))
-        W = W * sw[None, :]
-        H = H * sw[None, :]
-    train_sums = W.sum(axis=1)
-    if np.any(train_sums <= 0):
-        raise ValueError("every fold/replicate needs at least one training "
-                         "sample with positive weight")
-    held_sums = H.sum(axis=1)
-    valid_fold = held_sums > 0
-    if not valid_fold.any():
-        raise ValueError(
-            "no replicate has any held-out rows (every fold_weights row is "
-            "all-nonzero): there is nothing to cross-validate on — held-out "
-            "rows are a replicate's zero-weight rows")
+                "no replicate has any held-out rows (every fold_weights row "
+                "is all-nonzero): there is nothing to cross-validate on — "
+                "held-out rows are a replicate's zero-weight rows")
 
-    if lambdas is None:
-        lmax = lambda_max(design, y, datafit, sample_weight=sample_weight)
-        lambdas = lmax * np.geomspace(1.0, lambda_min_ratio, n_lambdas)
-    lambdas = _check_grid(lambdas)
-    nlam = len(lambdas)
+        if lambdas is None:
+            lmax = lambda_max(design, y, datafit, sample_weight=sample_weight)
+            lambdas = lmax * np.geomspace(1.0, lambda_min_ratio, n_lambdas)
+        lambdas = _check_grid(lambdas)
+        nlam = len(lambdas)
 
-    if engine is None:
-        engine = make_engine(penalty, datafit, shared=True, mesh=mesh,
-                             data_axis=data_axis, model_axis=model_axis,
-                             **engine_kw)
-    elif mesh is not None and engine.mesh is not mesh:
-        raise ValueError("cross_val_path(mesh=..., engine=...): the engine "
-                         "was built for a different mesh; pass mesh to "
-                         "make_engine instead")
-    n_tasks = y.shape[1] if y.ndim == 2 else 0
-    engine.validate(datafit, penalty, n_tasks, shape=design.shape,
-                    design=design, weighted=True)
+        if engine is None:
+            engine = make_engine(penalty, datafit, shared=True, mesh=mesh,
+                                 data_axis=data_axis, model_axis=model_axis,
+                                 **engine_kw)
+        elif mesh is not None and engine.mesh is not mesh:
+            raise ValueError("cross_val_path(mesh=..., engine=...): the "
+                             "engine was built for a different mesh; pass "
+                             "mesh to make_engine instead")
+        n_tasks = y.shape[1] if y.ndim == 2 else 0
+        engine.validate(datafit, penalty, n_tasks, shape=design.shape,
+                        design=design, weighted=True)
 
-    if engine.mesh is not None:
-        design, y, _ = _place_design(engine, design, y)
-    # per-fold train weights normalized to sum n (the row-subset-equivalent
-    # scaling, DESIGN.md §9) and held-out weights normalized to mean weights
-    Wd = jnp.asarray(W * (n / train_sums)[:, None], design.dtype)
-    Hd = jnp.asarray(
-        H * np.where(valid_fold, n / np.maximum(held_sums, 1e-300),
-                     0.0)[:, None], design.dtype)
-    if engine.mesh is not None:
-        from repro.launch.shardings import weight_spec
-        sh = NamedSharding(engine.mesh,
-                           weight_spec(engine.data_axis, n_lanes=1))
-        Wd, Hd = jax.device_put(Wd, sh), jax.device_put(Hd, sh)
-    F = W.shape[0]
-    # the grid-driver Lipschitz hot path: one weighted column-square
-    # reduction per fold (Pallas segment-sum kernel on ELL sparse designs)
-    L_folds = jnp.stack(
-        [design.lipschitz(datafit, Wd[f], backend=engine.config.backend)
-         for f in range(F)])
-    offset = datafit.grad_offset(p, design.dtype)
-    heldout = _heldout_fn(datafit)
+        if engine.mesh is not None:
+            design, y, _ = _place_design(engine, design, y)
+        # per-fold train weights normalized to sum n (the
+        # row-subset-equivalent scaling, DESIGN.md §9) and held-out weights
+        # normalized to mean weights
+        Wd = jnp.asarray(W * (n / train_sums)[:, None], design.dtype)
+        Hd = jnp.asarray(
+            H * np.where(valid_fold, n / np.maximum(held_sums, 1e-300),
+                         0.0)[:, None], design.dtype)
+        if engine.mesh is not None:
+            from repro.launch.shardings import weight_spec
+            sh = NamedSharding(engine.mesh,
+                               weight_spec(engine.data_axis, n_lanes=1))
+            Wd, Hd = jax.device_put(Wd, sh), jax.device_put(Hd, sh)
+        F = W.shape[0]
+        # the grid-driver Lipschitz hot path: one weighted column-square
+        # reduction per fold (Pallas segment-sum kernel on ELL sparse designs)
+        L_folds = jnp.stack(
+            [design.lipschitz(datafit, Wd[f], backend=engine.config.backend)
+             for f in range(F)])
+        offset = datafit.grad_offset(p, design.dtype)
+        heldout = _heldout_fn(datafit)
 
-    bshape = (p,) if n_tasks == 0 else (p, n_tasks)
-    xshape = (n,) if n_tasks == 0 else (n, n_tasks)
-    policy = BucketPolicy(p0=p0)
-    chunk = max(1, min(int(vmap_chunk), nlam))
-    S = F * chunk                          # the fixed lane pool
-    sync_every = max(1, int(sync_every))
-    sched = LaneScheduler(F, nlam, S, max_outer)
-    dtype = design.dtype
-    sp = obs.span if obs is not None else null_span
-    use_ring = obs is not None and getattr(obs, "rings", True)
-    fingerprint = _grid_fingerprint(
-        lambdas, W, [n, p, F, nlam, S, max_outer, sync_every, n_tasks,
-                     int(use_ring)], tol)
-    beta_sh, xb_sh = engine.lane_shardings(n_tasks)
+        bshape = (p,) if n_tasks == 0 else (p, n_tasks)
+        xshape = (n,) if n_tasks == 0 else (n, n_tasks)
+        policy = BucketPolicy(p0=p0)
+        chunk = max(1, min(int(vmap_chunk), nlam))
+        S = F * chunk                          # the fixed lane pool
+        sync_every = max(1, int(sync_every))
+        sched = LaneScheduler(F, nlam, S, max_outer)
+        dtype = design.dtype
+        use_ring = obs is not None and getattr(obs, "rings", True)
+        fingerprint = _grid_fingerprint(
+            lambdas, W, [n, p, F, nlam, S, max_outer, sync_every, n_tasks,
+                         int(use_ring)], tol)
+        beta_sh, xb_sh = engine.lane_shardings(n_tasks)
 
-    def _put_lanes(b_arr, x_arr):
-        b, x = jnp.asarray(b_arr, dtype), jnp.asarray(x_arr, dtype)
-        if beta_sh is not None:
-            b, x = jax.device_put(b, beta_sh), jax.device_put(x, xb_sh)
-        return b, x
+        def _put_lanes(b_arr, x_arr):
+            b, x = jnp.asarray(b_arr, dtype), jnp.asarray(x_arr, dtype)
+            if beta_sh is not None:
+                b, x = jax.device_put(b, beta_sh), jax.device_put(x, xb_sh)
+            return b, x
 
-    ckpt = checkpoint.make() if checkpoint is not None else None
-    if use_ring:
-        from repro.obs.rings import _FLOAT_FIELDS, _INT_FIELDS
-        curves_store = {
-            **{f: np.full((F, nlam, max_outer), np.nan, dtype)
-               for f in _FLOAT_FIELDS},
-            **{f: np.full((F, nlam, max_outer), -1, np.int32)
-               for f in _INT_FIELDS}}
-        counts_store = np.zeros((F, nlam), np.int64)
-    else:
-        curves_store = counts_store = None
-
-    # driver-side lane maps, kept verbatim for DEAD slots too: their stale
-    # entries keep every dispatch input identical between a resumed and an
-    # uninterrupted run, which is what makes resume bit-exact
-    lams_l = np.zeros(S, np.float64)
-    fold_host = np.zeros(S, np.int64)
-    lamidx_host = np.zeros(S, np.int64)
-    kkts_out = np.zeros((F, nlam))
-    eps_out = np.zeros((F, nlam), np.int64)
-    item_done = np.zeros((F, nlam), np.uint8)
-    times, occupancy = [], []
-    round_idx, total_outer, n_syncs = 0, 0, 0
-    resumed_from = None
-
-    if resume is not None:
-        template = _grid_state_template(sched, bshape, xshape, dtype,
-                                        fingerprint, use_ring, max_outer)
-        try:
-            state, step = restore_pytree(template, str(resume))
-        except KeyError as e:
-            # leaf-set mismatch = the snapshot was written with a different
-            # telemetry setting (obs on/off changes the checkpoint pytree)
-            raise ValueError(
-                f"resume={str(resume)!r}: checkpoint leaf set does not "
-                f"match this grid — pass the same obs= (telemetry on/off) "
-                f"the checkpointing run used ({e})") from e
-        state = jax.tree_util.tree_map(lambda a: np.array(a), state)
-        fp = state["fingerprint"]
-        if not (np.array_equal(fp["lambdas"], fingerprint["lambdas"])
-                and np.array_equal(fp["w_digest"], fingerprint["w_digest"])
-                and np.array_equal(fp["dims"], fingerprint["dims"])
-                and float(fp["tol"]) == float(tol)):
-            raise ValueError(
-                f"resume={str(resume)!r}: the checkpoint was written by a "
-                f"different grid (lambdas / fold weights / shapes / solver "
-                f"knobs mismatch); refusing to mix solver states")
-        sched.load_state(state["sched"])
-        betas_l, Xbs_l = _put_lanes(state["lane_betas"], state["lane_xbs"])
-        bank_b, bank_x = _put_lanes(state["bank_betas"], state["bank_xbs"])
-        out_betas = jnp.asarray(state["out_betas"], dtype)
-        out_loss = jnp.asarray(state["out_loss"], dtype)
-        lams_l = np.asarray(state["lane_lams"], np.float64)
-        fold_host = np.asarray(state["lane_fold"], np.int64)
-        lamidx_host = np.where(sched.lane_lam >= 0, sched.lane_lam,
-                               0).astype(np.int64)
-        kkts_out = np.array(state["kkts_out"])
-        eps_out = np.asarray(state["eps_out"], np.int64)
-        item_done = np.asarray(state["item_done"], np.uint8)
-        times = [float(t) for t in np.atleast_1d(state["times"])]
-        occupancy = [float(v) for v in np.atleast_1d(state["occupancy"])]
-        round_idx = int(state["round"])
-        bucket = int(state["bucket"])
-        total_outer = int(state["total_outer"])
-        n_syncs = int(state["n_syncs"])
-        # report CUMULATIVE sweep counters: the resumed GridResult equals
-        # the uninterrupted run's, dispatches included
-        dispatches0 = engine.n_dispatches - int(state["n_disp"])
-        resumed_from = int(step)
+        ckpt = checkpoint.make() if checkpoint is not None else None
         if use_ring:
-            curves_store = {k: np.array(v)
-                            for k, v in state["curves"].items()}
-            counts_store = np.array(state["n_recorded"])
-        if obs is not None:
-            obs.registry.inc("grid.resume.count")
-            obs.registry.set_gauge("grid.resume.step", resumed_from)
-        _emit_progress(progress, event="resume", round=round_idx,
-                       step=resumed_from, items_done=int(sched.n_retired),
-                       n_items=sched.total_items)
-    else:
-        dispatches0 = engine.n_dispatches
-        betas_l, Xbs_l = _put_lanes(np.zeros((S,) + bshape),
-                                    np.zeros((S,) + xshape))
-        bank_b, bank_x = _put_lanes(np.zeros((F,) + bshape),
-                                    np.zeros((F,) + xshape))
-        out_betas = jnp.zeros((F, nlam) + bshape, dtype)
-        out_loss = jnp.zeros((F, nlam), dtype)
-        for s, f, j in sched.fill():
-            lams_l[s], fold_host[s], lamidx_host[s] = lambdas[j], f, j
-        bucket = policy.first_bucket(0, p)
+            from repro.obs.rings import _FLOAT_FIELDS, _INT_FIELDS
+            curves_store = {
+                **{f: np.full((F, nlam, max_outer), np.nan, dtype)
+                   for f in _FLOAT_FIELDS},
+                **{f: np.full((F, nlam, max_outer), -1, np.int32)
+                   for f in _INT_FIELDS}}
+            counts_store = np.zeros((F, nlam), np.int64)
+        else:
+            curves_store = counts_store = None
 
-    def _snapshot():
-        st = {"round": np.int64(round_idx), "bucket": np.int64(bucket),
-              "total_outer": np.int64(total_outer),
-              "n_syncs": np.int64(n_syncs),
-              "n_disp": np.int64(engine.n_dispatches - dispatches0),
-              "sched": sched.state_dict(),
-              "lane_betas": betas_l, "lane_xbs": Xbs_l,
-              "lane_lams": lams_l, "lane_fold": fold_host,
-              "bank_betas": bank_b, "bank_xbs": bank_x,
-              "out_betas": out_betas, "out_loss": out_loss,
-              "kkts_out": kkts_out, "eps_out": eps_out,
-              "item_done": item_done,
-              "times": np.asarray(times, np.float64),
-              "occupancy": np.asarray(occupancy, np.float64),
-              "fingerprint": fingerprint}
-        if use_ring:
-            st["curves"] = curves_store
-            st["n_recorded"] = counts_store
-        return st
+        # driver-side lane maps, kept verbatim for DEAD slots too: their stale
+        # entries keep every dispatch input identical between a resumed and an
+        # uninterrupted run, which is what makes resume bit-exact
+        lams_l = np.zeros(S, np.float64)
+        fold_host = np.zeros(S, np.int64)
+        lamidx_host = np.zeros(S, np.int64)
+        kkts_out = np.zeros((F, nlam))
+        eps_out = np.zeros((F, nlam), np.int64)
+        item_done = np.zeros((F, nlam), np.uint8)
+        times, occupancy = [], []
+        round_idx, total_outer, n_syncs = 0, 0, 0
+        resumed_from = None
 
-    n_chunks = -(-nlam // chunk)        # nominal lower bound on rounds
-    t0 = _now()
-    dirty = True                        # lane tensors need (re)gathering
-    with sp("grid", folds=F, n_lambdas=nlam, chunk=chunk):
+        if resume is not None:
+            template = _grid_state_template(sched, bshape, xshape, dtype,
+                                            fingerprint, use_ring, max_outer)
+            try:
+                state, step = restore_pytree(template, str(resume))
+            except KeyError as e:
+                # leaf-set mismatch = the snapshot was written with a different
+                # telemetry setting (obs on/off changes the checkpoint pytree)
+                raise ValueError(
+                    f"resume={str(resume)!r}: checkpoint leaf set does not "
+                    f"match this grid — pass the same obs= (telemetry "
+                    f"on/off) the checkpointing run used ({e})") from e
+            state = jax.tree_util.tree_map(lambda a: np.array(a), state)
+            fp = state["fingerprint"]
+            if not (np.array_equal(fp["lambdas"], fingerprint["lambdas"])
+                    and np.array_equal(fp["w_digest"], fingerprint["w_digest"])
+                    and np.array_equal(fp["dims"], fingerprint["dims"])
+                    and float(fp["tol"]) == float(tol)):
+                raise ValueError(
+                    f"resume={str(resume)!r}: the checkpoint was written by a "
+                    f"different grid (lambdas / fold weights / shapes / "
+                    f"solver knobs mismatch); refusing to mix solver states")
+            sched.load_state(state["sched"])
+            betas_l, Xbs_l = _put_lanes(state["lane_betas"], state["lane_xbs"])
+            bank_b, bank_x = _put_lanes(state["bank_betas"], state["bank_xbs"])
+            out_betas = jnp.asarray(state["out_betas"], dtype)
+            out_loss = jnp.asarray(state["out_loss"], dtype)
+            lams_l = np.asarray(state["lane_lams"], np.float64)
+            fold_host = np.asarray(state["lane_fold"], np.int64)
+            lamidx_host = np.where(sched.lane_lam >= 0, sched.lane_lam,
+                                   0).astype(np.int64)
+            kkts_out = np.array(state["kkts_out"])
+            eps_out = np.asarray(state["eps_out"], np.int64)
+            item_done = np.asarray(state["item_done"], np.uint8)
+            times = [float(t) for t in np.atleast_1d(state["times"])]
+            occupancy = [float(v) for v in np.atleast_1d(state["occupancy"])]
+            round_idx = int(state["round"])
+            bucket = int(state["bucket"])
+            total_outer = int(state["total_outer"])
+            n_syncs = int(state["n_syncs"])
+            # report CUMULATIVE sweep counters: the resumed GridResult equals
+            # the uninterrupted run's, dispatches included
+            dispatches0 = engine.n_dispatches - int(state["n_disp"])
+            resumed_from = int(step)
+            if use_ring:
+                curves_store = {k: np.array(v)
+                                for k, v in state["curves"].items()}
+                counts_store = np.array(state["n_recorded"])
+            if obs is not None:
+                obs.registry.inc("grid.resume.count")
+                obs.registry.set_gauge("grid.resume.step", resumed_from)
+            _emit_progress(progress, event="resume", round=round_idx,
+                           step=resumed_from, items_done=int(sched.n_retired),
+                           n_items=sched.total_items)
+        else:
+            dispatches0 = engine.n_dispatches
+            betas_l, Xbs_l = _put_lanes(np.zeros((S,) + bshape),
+                                        np.zeros((S,) + xshape))
+            bank_b, bank_x = _put_lanes(np.zeros((F,) + bshape),
+                                        np.zeros((F,) + xshape))
+            out_betas = jnp.zeros((F, nlam) + bshape, dtype)
+            out_loss = jnp.zeros((F, nlam), dtype)
+            for s, f, j in sched.fill():
+                lams_l[s], fold_host[s], lamidx_host[s] = lambdas[j], f, j
+            bucket = policy.first_bucket(0, p)
+
+        def _snapshot():
+            st = {"round": np.int64(round_idx), "bucket": np.int64(bucket),
+                  "total_outer": np.int64(total_outer),
+                  "n_syncs": np.int64(n_syncs),
+                  "n_disp": np.int64(engine.n_dispatches - dispatches0),
+                  "sched": sched.state_dict(),
+                  "lane_betas": betas_l, "lane_xbs": Xbs_l,
+                  "lane_lams": lams_l, "lane_fold": fold_host,
+                  "bank_betas": bank_b, "bank_xbs": bank_x,
+                  "out_betas": out_betas, "out_loss": out_loss,
+                  "kkts_out": kkts_out, "eps_out": eps_out,
+                  "item_done": item_done,
+                  "times": np.asarray(times, np.float64),
+                  "occupancy": np.asarray(occupancy, np.float64),
+                  "fingerprint": fingerprint}
+            if use_ring:
+                st["curves"] = curves_store
+                st["n_recorded"] = counts_store
+            return st
+
+        n_chunks = -(-nlam // chunk)        # nominal lower bound on rounds
+        t0 = _now()
+        dirty = True                        # lane tensors need (re)gathering
+        if isinstance(grid_ev, dict):
+            grid_ev["args"].update(folds=F, n_lambdas=nlam, chunk=chunk)
         while not sched.done:
-            t_round = _now()
-            if dirty:
-                fold_dev = jnp.asarray(fold_host)
-                w_lanes = jnp.take(Wd, fold_dev, axis=0)
-                L_lanes = jnp.take(L_folds, fold_dev, axis=0)
-                H_lanes = jnp.take(Hd, fold_dev, axis=0)
-                lams_dev = jnp.asarray(lams_l, dtype)
-                dirty = False
-            occupancy.append(sched.occupancy)
-            mo = sched.dispatch_budget(sync_every)
-            ring = TelemetryRing.alloc(max_outer, dtype, lanes=S) \
-                if use_ring else None
-            bucket_used = bucket
             with sp("grid_round", round=round_idx, bucket=int(bucket),
                     n_lanes=S):
-                out = engine.chunk(bucket, design, y, lams_dev, betas_l,
-                                   Xbs_l, L_lanes, offset, datafit,
-                                   penalty, tol, eps_inner_frac, mo,
-                                   w=w_lanes, obs=ring)
-            if ring is not None:
-                (betas_l, Xbs_l, kkts_d, _, gcounts_d, neps_d, it_d,
-                 ring) = out
-            else:
-                betas_l, Xbs_l, kkts_d, _, gcounts_d, neps_d, it_d = out
-            # ONE blocking host sync per dispatch: the convergence scalars
-            # that drive the scheduler (the budget contract)
-            kkts_c, gcounts_c, neps_c, it = jax.device_get(
-                (kkts_d, gcounts_d, neps_d, it_d))
-            n_syncs += 1
-            it = int(it)
-            total_outer += it
-            rep = sched.observe(kkts_c, gcounts_c, neps_c, it, tol)
-            if ring is not None:
-                # obs-only output path (not a scheduler sync, matching the
-                # pre-§12 chunk driver's drain accounting)
-                curves, counts = ring.drain()
-                for s, r0 in zip(rep.active, rep.rec_before):
-                    f, j = int(fold_host[s]), int(lamidx_host[s])
-                    r0 = int(r0)
-                    r1 = min(r0 + it, max_outer)
-                    for k, v in curves.items():
-                        curves_store[k][f, j, r0:r1] = v[s, :r1 - r0]
-                    counts_store[f, j] = r1
-            if rep.retired:
-                # harvest retired lanes device-side: scatter into the
-                # output buffers, no host transfer mid-grid (dead lanes
-                # never reach the held-out scores — there is no padding)
-                loss_l = heldout(Xbs_l, y, H_lanes)
-                sl_np = np.array([r.slot for r in rep.retired])
-                fl = np.array([r.fold for r in rep.retired])
-                jl = np.array([r.lam_idx for r in rep.retired])
-                sl = jnp.asarray(sl_np)
-                out_betas = out_betas.at[fl, jl].set(betas_l[sl])
-                out_loss = out_loss.at[fl, jl].set(loss_l[sl])
-                kkts_out[fl, jl] = kkts_c[sl_np]
-                eps_out[fl, jl] = np.array(
-                    [r.n_epochs for r in rep.retired], np.int64)
-                item_done[fl, jl] = 1
-            if rep.bank_updates:
-                fb = np.array([u[0] for u in rep.bank_updates])
-                sb = jnp.asarray(np.array([u[1] for u in rep.bank_updates]))
-                bank_b = bank_b.at[fb].set(betas_l[sb])
-                bank_x = bank_x.at[fb].set(Xbs_l[sb])
-            assigns = sched.fill()
-            if assigns:
-                sl_np = np.array([a[0] for a in assigns])
-                fl = np.array([a[1] for a in assigns])
-                jl = np.array([a[2] for a in assigns])
-                fl_d = jnp.asarray(fl)
-                betas_l = betas_l.at[jnp.asarray(sl_np)].set(
-                    jnp.take(bank_b, fl_d, axis=0))
-                Xbs_l = Xbs_l.at[jnp.asarray(sl_np)].set(
-                    jnp.take(bank_x, fl_d, axis=0))
-                lams_l[sl_np] = lambdas[jl]
-                fold_host[sl_np] = fl
-                lamidx_host[sl_np] = jl
-                dirty = True
-            # bucket for the next dispatch: escalate when a continuing lane
-            # outgrew it; an all-retired boundary may de-escalate to what
-            # the fresh warm starts need (the old chunk-handoff behavior)
-            cont = rep.continuing
-            if len(cont):
-                if bucket < p and np.any(
-                        _GROWTH * gcounts_c[cont] > bucket):
-                    bucket = max(policy.escalate(bucket, p),
-                                 policy.next_bucket(
-                                     bucket,
-                                     int(np.max(gcounts_c[cont])), p))
-                if assigns:
-                    bucket = max(bucket, max(
-                        policy.first_bucket(int(sched.bank_gcount[f]), p)
-                        for f in fl))
-            elif assigns:
-                bucket = max(policy.first_bucket(
-                    int(sched.bank_gcount[f]), p) for f in fl)
-            round_idx += 1
-            times.append(_now() - t_round)
-            elapsed = _now() - t0
-            lambdas_done = int(np.sum(np.all(item_done == 1, axis=0)))
-            _emit_progress(
-                progress, event="bucket", chunk=round_idx - 1,
-                n_chunks=n_chunks, bucket=int(bucket_used),
-                lanes_converged=int(np.sum(kkts_c <= tol)),
-                n_lanes=S, lambdas_done=lambdas_done, n_lambdas=nlam,
-                elapsed_s=elapsed)
-            if rep.retired:
-                _emit_progress(
-                    progress, event="chunk", chunk=round_idx - 1,
-                    n_chunks=n_chunks, bucket=int(bucket_used),
-                    lanes_converged=int(np.sum(kkts_c <= tol)),
-                    n_lanes=S, lambdas_done=lambdas_done,
-                    n_lambdas=nlam, elapsed_s=elapsed,
-                    eta_s=elapsed / max(lambdas_done, 1)
-                    * (nlam - lambdas_done))
-            if ckpt is not None and round_idx % ckpt.every == 0 \
-                    and not sched.done:
-                with sp("grid_checkpoint", round=round_idx):
-                    ckpt.save(_snapshot(), round_idx)
+                t_round = _now()
+                if dirty:
+                    with sp("grid_lanes"):
+                        fold_dev = jnp.asarray(fold_host)
+                        w_lanes = jnp.take(Wd, fold_dev, axis=0)
+                        L_lanes = jnp.take(L_folds, fold_dev, axis=0)
+                        H_lanes = jnp.take(Hd, fold_dev, axis=0)
+                        lams_dev = jnp.asarray(lams_l, dtype)
+                    dirty = False
+                occupancy.append(sched.occupancy)
+                mo = sched.dispatch_budget(sync_every)
+                ring = TelemetryRing.alloc(max_outer, dtype, lanes=S) \
+                    if use_ring else None
+                bucket_used = bucket
+                with sp("grid_dispatch"):
+                    out = engine.chunk(bucket, design, y, lams_dev, betas_l,
+                                       Xbs_l, L_lanes, offset, datafit,
+                                       penalty, tol, eps_inner_frac, mo,
+                                       w=w_lanes, obs=ring)
+                if ring is not None:
+                    (betas_l, Xbs_l, kkts_d, _, gcounts_d, neps_d, it_d,
+                     ring) = out
+                else:
+                    betas_l, Xbs_l, kkts_d, _, gcounts_d, neps_d, it_d = out
+                # ONE blocking host sync per dispatch: the convergence scalars
+                # that drive the scheduler (the budget contract)
+                with sp("grid_sync"):
+                    kkts_c, gcounts_c, neps_c, it = jax.device_get(
+                        (kkts_d, gcounts_d, neps_d, it_d))
+                n_syncs += 1
+                it = int(it)
+                total_outer += it
+                with sp("grid_retire"):
+                    rep = sched.observe(kkts_c, gcounts_c, neps_c, it, tol)
+                    if ring is not None:
+                        # obs-only output path (not a scheduler sync, matching
+                        # the pre-§12 chunk driver's drain accounting)
+                        curves, counts = ring.drain()
+                        for s, r0 in zip(rep.active, rep.rec_before):
+                            f, j = int(fold_host[s]), int(lamidx_host[s])
+                            r0 = int(r0)
+                            r1 = min(r0 + it, max_outer)
+                            for k, v in curves.items():
+                                curves_store[k][f, j, r0:r1] = v[s, :r1 - r0]
+                            counts_store[f, j] = r1
+                    if rep.retired:
+                        # harvest retired lanes device-side: scatter into the
+                        # output buffers, no host transfer mid-grid (dead lanes
+                        # never reach the held-out scores — there is no
+                        # padding)
+                        loss_l = heldout(Xbs_l, y, H_lanes)
+                        sl_np = np.array([r.slot for r in rep.retired])
+                        fl = np.array([r.fold for r in rep.retired])
+                        jl = np.array([r.lam_idx for r in rep.retired])
+                        sl = jnp.asarray(sl_np)
+                        out_betas = out_betas.at[fl, jl].set(betas_l[sl])
+                        out_loss = out_loss.at[fl, jl].set(loss_l[sl])
+                        kkts_out[fl, jl] = kkts_c[sl_np]
+                        eps_out[fl, jl] = np.array(
+                            [r.n_epochs for r in rep.retired], np.int64)
+                        item_done[fl, jl] = 1
+                if rep.bank_updates:
+                    with sp("grid_bank"):
+                        fb = np.array([u[0] for u in rep.bank_updates])
+                        sb = jnp.asarray(
+                            np.array([u[1] for u in rep.bank_updates]))
+                        bank_b = bank_b.at[fb].set(betas_l[sb])
+                        bank_x = bank_x.at[fb].set(Xbs_l[sb])
+                with sp("grid_fill"):
+                    assigns = sched.fill()
+                    if assigns:
+                        sl_np = np.array([a[0] for a in assigns])
+                        fl = np.array([a[1] for a in assigns])
+                        jl = np.array([a[2] for a in assigns])
+                        fl_d = jnp.asarray(fl)
+                        betas_l = betas_l.at[jnp.asarray(sl_np)].set(
+                            jnp.take(bank_b, fl_d, axis=0))
+                        Xbs_l = Xbs_l.at[jnp.asarray(sl_np)].set(
+                            jnp.take(bank_x, fl_d, axis=0))
+                        lams_l[sl_np] = lambdas[jl]
+                        fold_host[sl_np] = fl
+                        lamidx_host[sl_np] = jl
+                        dirty = True
+                with sp("grid_schedule"):
+                    # bucket for the next dispatch: escalate when a continuing
+                    # lane outgrew it; an all-retired boundary may de-escalate
+                    # to what the fresh warm starts need (the old chunk-handoff
+                    # behavior)
+                    cont = rep.continuing
+                    if len(cont):
+                        if bucket < p and np.any(
+                                _GROWTH * gcounts_c[cont] > bucket):
+                            bucket = max(policy.escalate(bucket, p),
+                                         policy.next_bucket(
+                                             bucket,
+                                             int(np.max(gcounts_c[cont])), p))
+                        if assigns:
+                            bucket = max(bucket, max(
+                                policy.first_bucket(int(sched.bank_gcount[f]),
+                                                    p)
+                                for f in fl))
+                    elif assigns:
+                        bucket = max(policy.first_bucket(
+                            int(sched.bank_gcount[f]), p) for f in fl)
+                    round_idx += 1
+                    times.append(_now() - t_round)
+                    elapsed = _now() - t0
+                    lambdas_done = int(np.sum(np.all(item_done == 1, axis=0)))
+                    _emit_progress(
+                        progress, event="bucket", chunk=round_idx - 1,
+                        n_chunks=n_chunks, bucket=int(bucket_used),
+                        lanes_converged=int(np.sum(kkts_c <= tol)),
+                        n_lanes=S, lambdas_done=lambdas_done, n_lambdas=nlam,
+                        elapsed_s=elapsed)
+                    if rep.retired:
+                        _emit_progress(
+                            progress, event="chunk", chunk=round_idx - 1,
+                            n_chunks=n_chunks, bucket=int(bucket_used),
+                            lanes_converged=int(np.sum(kkts_c <= tol)),
+                            n_lanes=S, lambdas_done=lambdas_done,
+                            n_lambdas=nlam, elapsed_s=elapsed,
+                            eta_s=elapsed / max(lambdas_done, 1)
+                            * (nlam - lambdas_done))
+                if ckpt is not None and round_idx % ckpt.every == 0 \
+                        and not sched.done:
+                    with sp("grid_checkpoint", round=round_idx):
+                        ckpt.save(_snapshot(), round_idx)
 
-    if ckpt is not None:
-        ckpt.wait()                     # surface async write errors here
-    betas_np, loss_np = jax.device_get((out_betas, out_loss))
-    betas_out = np.array(betas_np, np.float64)
-    loss_out = np.array(loss_np, np.float64)
-    loss_out[~valid_fold] = np.nan
-    cv_mean = np.mean(loss_out[valid_fold], axis=0) if valid_fold.any() \
-        else np.full(nlam, np.nan)
-    cv_std = np.std(loss_out[valid_fold], axis=0) if valid_fold.any() \
-        else np.full(nlam, np.nan)
-    best = int(np.argmin(cv_mean)) if np.isfinite(cv_mean).any() else 0
-    occ = np.asarray(occupancy)
-    grid = GridResult(lambdas=lambdas, betas=betas_out, cv_loss=loss_out,
-                      cv_mean=cv_mean, cv_std=cv_std, best_index=best,
-                      best_lambda=float(lambdas[best]), kkts=kkts_out,
-                      n_epochs=eps_out, fold_weights=W, n_outer=total_outer,
-                      times=np.asarray(times), occupancy=occ,
-                      n_rounds=round_idx, resumed_from=resumed_from,
-                      retraces=dict(engine.retraces),
-                      n_dispatches=engine.n_dispatches - dispatches0,
-                      n_host_syncs=n_syncs)
-    reg = grid.diagnostics.registry
-    reg.set_counter("grid.n_host_syncs", n_syncs)
-    reg.set_counter("grid.n_dispatches", grid.n_dispatches)
-    reg.set_counter("grid.n_outer", total_outer)
-    reg.set_counter("grid.n_rounds", round_idx)
-    reg.set_mapping("grid.retraces", dict(engine.retraces))
-    reg.set_gauge("grid.lane_occupancy",
-                  float(occ.mean()) if occ.size else 1.0)
-    for v in occupancy:
-        reg.observe("grid.occupancy", float(v))
-    if curves_store is not None:
-        grid.diagnostics.curves.update(curves_store)
-        grid.diagnostics.n_recorded = counts_store
-    if obs is not None:
-        obs.registry.inc("grid.count")
-        obs.registry.set_gauge("grid.lane_occupancy",
-                               float(occ.mean()) if occ.size else 1.0)
-        obs.note_solve(grid.diagnostics)
+        if ckpt is not None:
+            ckpt.wait()                     # surface async write errors here
+        betas_np, loss_np = jax.device_get((out_betas, out_loss))
+        betas_out = np.array(betas_np, np.float64)
+        loss_out = np.array(loss_np, np.float64)
+        loss_out[~valid_fold] = np.nan
+        cv_mean = np.mean(loss_out[valid_fold], axis=0) if valid_fold.any() \
+            else np.full(nlam, np.nan)
+        cv_std = np.std(loss_out[valid_fold], axis=0) if valid_fold.any() \
+            else np.full(nlam, np.nan)
+        best = int(np.argmin(cv_mean)) if np.isfinite(cv_mean).any() else 0
+        occ = np.asarray(occupancy)
+        grid = GridResult(lambdas=lambdas, betas=betas_out, cv_loss=loss_out,
+                          cv_mean=cv_mean, cv_std=cv_std, best_index=best,
+                          best_lambda=float(lambdas[best]), kkts=kkts_out,
+                          n_epochs=eps_out, fold_weights=W,
+                          n_outer=total_outer,
+                          times=np.asarray(times), occupancy=occ,
+                          n_rounds=round_idx, resumed_from=resumed_from,
+                          retraces=dict(engine.retraces),
+                          n_dispatches=engine.n_dispatches - dispatches0,
+                          n_host_syncs=n_syncs)
+        reg = grid.diagnostics.registry
+        reg.set_counter("grid.n_host_syncs", n_syncs)
+        reg.set_counter("grid.n_dispatches", grid.n_dispatches)
+        reg.set_counter("grid.n_outer", total_outer)
+        reg.set_counter("grid.n_rounds", round_idx)
+        reg.set_mapping("grid.retraces", dict(engine.retraces))
+        reg.set_gauge("grid.lane_occupancy",
+                      float(occ.mean()) if occ.size else 1.0)
+        for v in occupancy:
+            reg.observe("grid.occupancy", float(v))
+        if curves_store is not None:
+            grid.diagnostics.curves.update(curves_store)
+            grid.diagnostics.n_recorded = counts_store
+        if obs is not None:
+            obs.registry.inc("grid.count")
+            obs.registry.set_gauge("grid.lane_occupancy",
+                                   float(occ.mean()) if occ.size else 1.0)
+            obs.note_solve(grid.diagnostics)
     return grid
 
 

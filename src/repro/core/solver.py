@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
-from repro.obs import SolveDiagnostics, TelemetryRing, null_span
+from repro.obs import SolveDiagnostics, TelemetryRing, span
 
 from .engine import EngineConfig, SolveEngine, as_design, get_engine
 from .working_set import BucketPolicy
@@ -235,9 +235,10 @@ def solve(X, y, datafit, penalty, *, tol=1e-6, max_outer=50, max_epochs=1000,
         Observability handle (DESIGN.md §11). When given, the solve carries
         a device telemetry ring through the fused step — per-outer
         kkt/gap/objective/ws curves land on ``result.diagnostics`` — and
-        opens nested tracer spans (solve → outer → dispatch/sync) on
-        ``obs.tracer``. Zero extra dispatches; one extra blocking readback
-        at drain time. ``obs=None`` (the default) statically elides every
+        records the solve's nested spans (solve → outer → dispatch/sync)
+        on ``obs.tracer``; with or without ``obs`` they are written to any
+        profiler capture as ``repro/<name>`` (DESIGN.md §11.2). Zero extra
+        dispatches; one extra blocking readback at drain time. ``obs=None`` (the default) statically elides every
         telemetry op: the compiled program is bit-identical to the pre-obs
         one (same mechanism as the ``w=None`` weight leaf).
 
@@ -253,59 +254,62 @@ def solve(X, y, datafit, penalty, *, tol=1e-6, max_outer=50, max_epochs=1000,
     >>> res.converged, res.beta.shape
     (True, (p,))
     """
-    # a mesh solve places a host array straight onto its shards
-    design = as_design(X, on_host=(mesh if engine is None
-                                   else engine.mesh) is not None)
-    n_rows, p = design.shape
-    if not use_ws:
-        p0 = p
-    if use_fp_score is None:
-        use_fp_score = not penalty.HAS_SUBDIFF
-    gram = datafit.HAS_GRAM if use_gram == "auto" else bool(use_gram)
-    if n_tasks is None:
-        n_tasks = y.shape[1] if (hasattr(y, "ndim") and y.ndim == 2) else 0
+    sp = obs.span if obs is not None else span
+    with sp("solve") as solve_ev:
+        # a mesh solve places a host array straight onto its shards
+        design = as_design(X, on_host=(mesh if engine is None
+                                       else engine.mesh) is not None)
+        n_rows, p = design.shape
+        if not use_ws:
+            p0 = p
+        if use_fp_score is None:
+            use_fp_score = not penalty.HAS_SUBDIFF
+        gram = datafit.HAS_GRAM if use_gram == "auto" else bool(use_gram)
+        if n_tasks is None:
+            n_tasks = y.shape[1] if (hasattr(y, "ndim") and y.ndim == 2) \
+                else 0
 
-    if engine is None:
-        engine = make_engine(penalty, datafit, M=M, max_epochs=max_epochs,
-                             accel=accel, use_fp_score=use_fp_score,
-                             use_gram=gram, use_kernels=use_kernels,
-                             mesh=mesh, data_axis=data_axis,
-                             model_axis=model_axis, shared=True)
-    elif mesh is not None and engine.mesh is not mesh:
-        raise ValueError("solve(mesh=..., engine=...): the engine was built "
-                         "for a different mesh; pass mesh to make_engine "
-                         "instead")
-    engine.validate(datafit, penalty, n_tasks, shape=design.shape,
-                    design=design, weighted=sample_weight is not None)
-    policy = bucket_policy or BucketPolicy(p0=p0)
+        if engine is None:
+            engine = make_engine(penalty, datafit, M=M, max_epochs=max_epochs,
+                                 accel=accel, use_fp_score=use_fp_score,
+                                 use_gram=gram, use_kernels=use_kernels,
+                                 mesh=mesh, data_axis=data_axis,
+                                 model_axis=model_axis, shared=True)
+        elif mesh is not None and engine.mesh is not mesh:
+            raise ValueError("solve(mesh=..., engine=...): the engine was "
+                             "built for a different mesh; pass mesh to "
+                             "make_engine instead")
+        engine.validate(datafit, penalty, n_tasks, shape=design.shape,
+                        design=design, weighted=sample_weight is not None)
+        policy = bucket_policy or BucketPolicy(p0=p0)
 
-    w = None if sample_weight is None \
-        else normalize_weights(sample_weight, n_rows, design.dtype)
-    if engine.mesh is not None:
-        design, y, w = _place_design(engine, design, y, w)
-    L = design.lipschitz(datafit) if w is None \
-        else design.lipschitz(datafit, w, backend=engine.config.backend)
-    offset = datafit.grad_offset(p, design.dtype)
-    bshape = (p, n_tasks) if n_tasks else (p,)
-    beta = jnp.zeros(bshape, design.dtype) if beta0 is None \
-        else jnp.asarray(beta0)
-    if engine.mesh is not None:
-        from repro.launch.shardings import task_spec
-        _, _, bs = engine._specs()
-        beta = jax.device_put(
-            beta, NamedSharding(engine.mesh, task_spec(bs, n_tasks)))
-    Xb = design.matvec(beta)
+        w = None if sample_weight is None \
+            else normalize_weights(sample_weight, n_rows, design.dtype)
+        if engine.mesh is not None:
+            design, y, w = _place_design(engine, design, y, w)
+        L = design.lipschitz(datafit) if w is None \
+            else design.lipschitz(datafit, w, backend=engine.config.backend)
+        offset = datafit.grad_offset(p, design.dtype)
+        bshape = (p, n_tasks) if n_tasks else (p,)
+        beta = jnp.zeros(bshape, design.dtype) if beta0 is None \
+            else jnp.asarray(beta0)
+        if engine.mesh is not None:
+            from repro.launch.shardings import task_spec
+            _, _, bs = engine._specs()
+            beta = jax.device_put(
+                beta, NamedSharding(engine.mesh, task_spec(bs, n_tasks)))
+        Xb = design.matvec(beta)
 
-    res = SolveResult(beta=beta, kkt=float("inf"), converged=False,
-                      n_outer=0, n_epochs=0)
-    sp = obs.span if obs is not None else null_span
-    ring = None
-    if obs is not None and getattr(obs, "rings", True):
-        ring = TelemetryRing.alloc(max_outer, design.dtype)
-    t0 = time.perf_counter()
+        res = SolveResult(beta=beta, kkt=float("inf"), converged=False,
+                          n_outer=0, n_epochs=0)
+        if isinstance(solve_ev, dict):
+            solve_ev["args"].update(n=n_rows, p=p, tol=tol,
+                                    backend=engine.config.backend)
+        ring = None
+        if obs is not None and getattr(obs, "rings", True):
+            ring = TelemetryRing.alloc(max_outer, design.dtype)
+        t0 = time.perf_counter()
 
-    with sp("solve", n=n_rows, p=p, tol=tol,
-            backend=engine.config.backend):
         # first-bucket sizing: cold starts have empty generalized support;
         # warm starts probe it once (one launch + one sync per solve, not
         # per iter)
@@ -337,7 +341,7 @@ def solve(X, y, datafit, penalty, *, tol=1e-6, max_outer=50, max_epochs=1000,
                 with sp("sync"):
                     kkt, obj, gcount, n_ep, cov = jax.device_get(
                         (kkt_d, obj_d, gcount_d, nep_d, cov_d))
-                if ev is not None:
+                if isinstance(ev, dict):
                     ev["args"]["compiled"] = \
                         sum(engine.retraces.values()) > r0
             res.n_host_syncs += 1

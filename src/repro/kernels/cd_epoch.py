@@ -114,6 +114,7 @@ def cd_epoch_gram_pallas(G, c, beta0, q0, L, penalty_cls, params, *, epochs=1,
         out_specs=[vec, vec],
         out_shape=[jax.ShapeDtypeStruct((1, Kp), G.dtype)] * 2,
         interpret=interpret,
+        name="cd_epoch_gram",
     )(_pad_to(_pad_to(G, Kp, 0), Kp, 1), row(c), row(L),
       params.astype(G.dtype), row(beta0), row(q0))
     return beta[0, :K], q[0, :K]
@@ -201,5 +202,6 @@ def cd_epoch_xb_pallas(Xt_ws, y, beta0, Xb0, L, offset, penalty_cls, params,
         out_shape=[jax.ShapeDtypeStruct((1, Kp), Xt_ws.dtype),
                    jax.ShapeDtypeStruct((1, n), Xt_ws.dtype)],
         interpret=interpret,
+        name="cd_epoch_xb",
     )(*operands)
     return beta[0, :K], Xb[0]

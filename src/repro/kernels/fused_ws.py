@@ -219,6 +219,7 @@ def fused_ws_pallas(X, r, beta, L, offset, gsupp, penalty_cls, params,
         scratch_shapes=[pltpu.VMEM((kc, bp), X.dtype)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="fused_ws",
     )(X, rows, pad(beta_rows), pad(L[None, :]), pad(offset[None, :]),
       pad(gsupp[None, :]), params.astype(X.dtype))
     grad = grad[0, :p] if squeeze else grad[:, :p].T

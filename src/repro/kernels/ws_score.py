@@ -97,5 +97,6 @@ def ws_score_pallas(X, r, beta, L, offset, penalty_cls, params, *, w=None,
         out_shape=jax.ShapeDtypeStruct((p, 1), X.dtype),
         scratch_shapes=[pltpu.VMEM((bp, 1), X.dtype)],
         interpret=interpret,
+        name="ws_score",
     )(*operands)
     return out[:, 0]

@@ -5,10 +5,13 @@ Three parts, threaded through the whole solve stack:
   * device-side telemetry rings (:mod:`repro.obs.rings`): preallocated
     ``[max_outer]`` pytree buffers recording per-outer KKT/gap/working-set
     curves INSIDE the fused dispatch, drained once per solve;
-  * a span tracer (:mod:`repro.obs.trace`) with Chrome-trace/Perfetto JSON
-    export plus a central :class:`MetricsRegistry`
-    (:mod:`repro.obs.registry`) that the legacy ad-hoc counters are views
-    into;
+  * program spans (:func:`span`, :mod:`repro.obs.trace`): always present,
+    written into the profiler's own trace as ``repro/<name>`` whenever a
+    profile is captured (``jax.profiler.trace``), on the device events'
+    clock; with an ``Obs`` attached they are also recorded by a span tracer
+    with Chrome-trace/Perfetto JSON export. A central
+    :class:`MetricsRegistry` (:mod:`repro.obs.registry`) holds the counters
+    the legacy ad-hoc attributes are views into;
   * result surfaces: ``SolveResult.diagnostics`` et al.
     (:mod:`repro.obs.diagnostics`), the ``cross_val_path`` progress
     callback, and the ``python -m repro.obs.report`` CLI.
@@ -24,34 +27,40 @@ Quickstart::
     obs.export_chrome("trace.json")       # open in ui.perfetto.dev
     obs.dump("run.json")                  # python -m repro.obs.report run.json
 
-Everything is opt-in: ``obs=None`` (the default) statically elides every
+The spans, by driver (DESIGN.md §11.2):
+
+  solve            solve > probe, outer > dispatch, sync; drain
+  cross_val_path   grid > grid_round > grid_lanes, grid_dispatch, grid_sync,
+                   grid_retire, grid_bank, grid_fill, grid_schedule;
+                   grid_checkpoint
+  reg_path         path > lambda (> solve ...) or lambda_chunk
+  server           serve.flush > serve.dispatch; serve.refit
+
+The stages of the fused step are ``jax.named_scope``s in
+``SolveEngine._step_body`` (score, select, gather, inner_solve, scatter,
+telemetry) and ``_chunk_loop`` (chunk_control): they name the ops of the
+compiled program, so a device trace's ops map to a stage.
+
+The rest is opt-in: ``obs=None`` (the default) statically elides every
 device-side op — the trace is bit-identical to the pre-obs program and adds
 zero dispatches (asserted by tests/test_obs.py and the CI-enforced
-``telemetry_overhead`` budget in BENCH_engine.json).
+``telemetry_overhead`` budget in BENCH_engine.json) — and records no tracer
+event; its spans cost one profiler annotation each (about a microsecond
+with no profile being captured).
 """
 from __future__ import annotations
 
 import json
-from contextlib import nullcontext
 
 import numpy as np
 
 from .diagnostics import Diagnostics, SolveDiagnostics
 from .registry import MetricsRegistry
 from .rings import TelemetryRing, lasso_duality_gap
-from .trace import Tracer
+from .trace import Tracer, span
 
 __all__ = ["Obs", "Tracer", "MetricsRegistry", "TelemetryRing",
-           "Diagnostics", "SolveDiagnostics", "lasso_duality_gap",
-           "null_span"]
-
-
-def null_span(name, **args):
-    """The span used when no Obs is attached: a reusable nullcontext
-    (yields None, so span-arg attachment sites guard on ``ev is not
-    None``)."""
-    del name, args
-    return nullcontext()
+           "Diagnostics", "SolveDiagnostics", "lasso_duality_gap", "span"]
 
 
 class Obs:
@@ -65,25 +74,23 @@ class Obs:
         kkt/gap/ws curves on the result's ``diagnostics``; one extra host
         readback per solve at drain time, zero extra dispatches).
     trace : bool, optional
-        Collect host-side spans (solve → outer → dispatch/sync, path →
-        lambda, grid → chunk/bucket) on :attr:`tracer`.
-    annotate : bool, optional
-        Additionally enter a ``jax.profiler.TraceAnnotation`` per span so
-        the names land inside XLA profiler captures.
+        Also record the program's spans (solve → outer → dispatch/sync,
+        path → lambda, grid → grid_round → ...) on :attr:`tracer`; they
+        land in the profiler's trace either way.
     """
 
-    def __init__(self, rings: bool = True, trace: bool = True,
-                 annotate: bool = False):
+    def __init__(self, rings: bool = True, trace: bool = True):
         self.rings = rings
         self.trace = trace
-        self.tracer = Tracer(annotate=annotate)
+        self.tracer = Tracer()
         self.registry = MetricsRegistry()
         self.solves: list = []          # Diagnostics of every solve seen
 
     def span(self, name, **args):
-        """Open a tracer span (a no-op context when ``trace=False``)."""
+        """Open a program span, recorded on :attr:`tracer` too when
+        ``trace=True`` (then it yields the tracer's event dict)."""
         if not self.trace:
-            return nullcontext()
+            return span(name, **args)
         return self.tracer.span(name, **args)
 
     def note_solve(self, diagnostics: Diagnostics):

@@ -1,16 +1,18 @@
-"""Span tracer with Chrome-trace export (DESIGN.md §11.2).
+"""Program spans and the span tracer with Chrome-trace export
+(DESIGN.md §11.2).
 
-``Tracer.span`` is a context manager emitting nested wall-clock spans —
-solve → outer → dispatch/sync, path → lambda, grid → chunk/bucket — as
-Chrome trace "complete" (``ph: "X"``) events. ``export_chrome`` writes the
-standard ``{"traceEvents": [...]}`` JSON that chrome://tracing and Perfetto
-(ui.perfetto.dev) open directly; nesting is inferred from time containment
-per thread lane, which the with-statement discipline guarantees.
+:func:`span` is the one span of the program: a
+``jax.profiler.TraceAnnotation`` named ``repro/<name>``, so every span lands
+in the profiler's own trace, on the clock of the device's events, whenever
+a profile is being captured — and costs about a microsecond when none is.
 
-With ``annotate=True`` every span additionally enters a
-``jax.profiler.TraceAnnotation``, so the same span names show up inside an
-XLA profiler trace when one is being captured (a no-op passthrough
-otherwise — failures to import or enter the annotation are swallowed).
+``Tracer.span`` enters the same annotation and also records the span as a
+Chrome trace "complete" (``ph: "X"``) event — solve → outer →
+dispatch/sync, path → lambda, grid → grid_round → grid_dispatch/... .
+``export_chrome`` writes the standard ``{"traceEvents": [...]}`` JSON that
+chrome://tracing and Perfetto (ui.perfetto.dev) open directly; nesting is
+inferred from time containment per thread lane, which the with-statement
+discipline guarantees.
 """
 from __future__ import annotations
 
@@ -19,14 +21,25 @@ import threading
 import time
 from contextlib import contextmanager
 
-__all__ = ["Tracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "span"]
+
+PREFIX = "repro/"
+
+
+def span(name: str, **args):
+    """Open the program span `name`: a profiler annotation
+    ``repro/<name>`` carrying `args` (keep them to ints already at hand;
+    they are formatted only while a profile is being captured). Entering it
+    yields the annotation, never a tracer event."""
+    return TraceAnnotation(PREFIX + name, **args)
 
 
 class Tracer:
     """Collects nested spans; host-side, append-only, microsecond units."""
 
-    def __init__(self, annotate: bool = False):
-        self.annotate = annotate
+    def __init__(self):
         self.events: list = []
         self._t0 = time.perf_counter()
         self._lock = threading.Lock()
@@ -42,7 +55,8 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, **args):
-        """Open a span; yields the (mutable) event dict so callers can
+        """Open a span, in the profiler's trace as :func:`span` does and
+        on this tracer; yields the (mutable) event dict so callers can
         attach args discovered mid-span (e.g. ``ev["args"]["compiled"]``
         once a dispatch is known to have retraced)."""
         start = time.perf_counter()
@@ -50,19 +64,10 @@ class Tracer:
               "ts": (start - self._t0) * 1e6,
               "args": dict(args, depth=getattr(self._depth, "v", 0))}
         self._depth.v = ev["args"]["depth"] + 1
-        ann = None
-        if self.annotate:
-            try:
-                from jax.profiler import TraceAnnotation
-                ann = TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
         try:
-            yield ev
+            with span(name, **args):
+                yield ev
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
             self._depth.v = ev["args"]["depth"]
             ev["dur"] = (time.perf_counter() - start) * 1e6
             with self._lock:

@@ -43,7 +43,7 @@ import numpy as np
 from repro.bucketing import pow2_bucket
 from repro.core.engine import is_scipy_sparse, pack_support, scatter_packed
 from repro.core.solver import solve
-from repro.obs import null_span
+from repro.obs import span
 from repro.obs.registry import MetricsRegistry
 
 __all__ = ["CoefficientBank", "SparseModelServer", "PredictResult",
@@ -355,7 +355,7 @@ class SparseModelServer:
         if not self._pending:
             return []
         pending, self._pending = self._pending, []
-        sp = self.obs.span if self.obs is not None else null_span
+        sp = self.obs.span if self.obs is not None else span
         dtype = self.bank.dtype
         keys = self.metrics.mapping("serve.dispatch_keys")
         out = {}
@@ -449,7 +449,7 @@ class SparseModelServer:
         """
         slot = self.bank.slot(model_id)
         grp = self.bank.group(slot.bucket)
-        sp = self.obs.span if self.obs is not None else null_span
+        sp = self.obs.span if self.obs is not None else span
         with sp("serve.refit", model=str(model_id), bucket=slot.bucket):
             beta0 = scatter_packed(grp.idx[slot.row], grp.val[slot.row],
                                    self.p)

@@ -146,6 +146,8 @@ def test_chip_smoke_mesh_phase_on_four_cpu_devices(tmp_path):
 def test_compile_cache_placement(monkeypatch):
     from repro.compile_cache import CHECKOUT, enable_compile_cache
     was = jax.config.jax_compilation_cache_dir
+    was_key = jax.config.jax_compilation_cache_include_metadata_in_key
+    was_frames = jax.config.jax_traceback_in_locations_limit
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
         jax.config.update("jax_compilation_cache_dir", None)
@@ -155,5 +157,19 @@ def test_compile_cache_placement(monkeypatch):
         path = enable_compile_cache()
         assert path == str(ROOT / ".jax-cache") == str(CHECKOUT / ".jax-cache")
         assert jax.config.jax_compilation_cache_dir == path
+        # op names (stage scopes) are part of what a cached program is;
+        # source frames are not
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert jax.config.jax_traceback_in_locations_limit == 0
+
+        def f(x):
+            with jax.named_scope("score"):
+                return jnp.sin(x)
+        hlo = jax.jit(f).lower(jnp.ones(3)).compile().as_text()
+        assert 'op_name="jit(f)/score/sin"' in hlo
+        assert "stack_frame_id" not in hlo
     finally:
         jax.config.update("jax_compilation_cache_dir", was)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          was_key)
+        jax.config.update("jax_traceback_in_locations_limit", was_frames)

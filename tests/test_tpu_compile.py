@@ -94,3 +94,44 @@ def test_xb_epoch_compiles_for_v5e(one_chip, compile_f32, kind):
                        s(K), L1, s(penalty_arity(L1)), kind,
                        interpret=False)
     assert "tpu_custom_call" in text
+
+
+def test_outer_step_ops_name_one_stage(one_chip, compile_f32):
+    """The fused step at the dense benchmark's size (n=1,000, p=2,000,
+    bucket 1,024): every fusion, custom call and dynamic-(update-)slice the
+    chip's compiler emits (outside fused computations, whose instructions
+    run inside their fusion's event) that carries an ``op_name`` names
+    exactly one stage scope of ``SolveEngine._step_body``, so a device
+    trace's ops map to stages (``bench.span_reduce``). A few fusions XLA
+    builds itself inside the inner loops carry no ``op_name`` at all; they
+    stay a small minority."""
+    import re
+
+    from bench.span_reduce import stage_scopes
+    from repro.core import L1, Quadratic, make_engine
+    from repro.core.engine import DenseDesign
+    n, p, bucket = 1_000, 2_000, 1_024
+    s = lambda *shape: _spec(one_chip, *shape)
+    engine = make_engine(L1(0.1), Quadratic())
+    text = compile_f32(engine._jstep, DenseDesign(s(n, p)), s(n), None,
+                       s(p), s(n), s(p), s(p), Quadratic(), L1(0.1), 1e-4,
+                       0.3, bucket=bucket, obs=None)
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    kinds = re.compile(r"= \S+ (fusion|custom-call|dynamic-slice|"
+                       r"dynamic-update-slice)\(")
+    comp, named, unnamed = None, [], []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+        elif comp not in fused and kinds.search(line):
+            name = re.search(r'op_name="([^"]*)"', line)
+            if name is None:
+                unnamed.append(line.split("=")[0].strip())
+                continue
+            scopes = stage_scopes(name.group(1))
+            assert len(scopes) == 1, (name.group(1), line.strip()[:200])
+            named.append(scopes[0])
+    assert {"score", "select", "gather", "inner_solve", "scatter"} \
+        <= set(named)
+    assert len(unnamed) * 5 < len(named), unnamed
