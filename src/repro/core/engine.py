@@ -75,7 +75,7 @@ from repro.obs.registry import MetricsRegistry
 from repro.obs.rings import gap_supported, quadratic_l1_gap
 
 from .anderson import anderson_extrapolate
-from .cd import cd_epoch_gram, cd_epoch_xb
+from .cd import cd_epoch_xb, cd_sweep_block, gram_sweep, gram_sweep_epoch
 from .working_set import (candidate_columns, gather_ws_cols, gather_ws_vec,
                           scatter_ws, select_working_set,
                           select_working_set_local, shard_ws_mask,
@@ -345,6 +345,9 @@ class WorkingSetContext:
                                      # nonzero coordinates OUTSIDE ws (Xb
                                      # solvers; Box pins coords at C with
                                      # empty generalized support)
+    sweep: object = None             # G, c, L laid out for the blocked CD
+                                     # sweep (core/cd.py GramSweep; Gram
+                                     # solvers on the jax backend)
 
 
 def _psum_if(x, axis):
@@ -486,7 +489,32 @@ def _scalar_epoch_kernel_ok(penalty, beta) -> bool:
 
 class GramSolver(SubproblemSolver):
     """Quadratic datafits: state q = G beta stays K-sized (VMEM-resident on
-    TPU through the Pallas backend; see kernels/cd_epoch.py)."""
+    TPU through the Pallas backend; see kernels/cd_epoch.py). On the jax
+    backend each epoch is the blocked sweep of core/cd.py, on G, c and L
+    laid out once per inner solve, with q's update in the layout that
+    `axpy_per_column` selects; `metrics`, when given, records its block
+    size per working-set size K under ``engine.cd_sweep_block`` as the
+    solve is traced."""
+
+    def __init__(self, config: EngineConfig, metrics=None,
+                 axpy_per_column=False):
+        super().__init__(config)
+        self.metrics = metrics
+        self.axpy_per_column = axpy_per_column
+
+    def _kernel_epochs(self, ctx, beta):
+        return self.config.backend == "pallas" and _scalar_epoch_kernel_ok(
+            ctx.penalty, beta)
+
+    def solve(self, ctx, beta0, eps, aux0=None, track=False):
+        if not self._kernel_epochs(ctx, beta0):
+            K = ctx.G.shape[0]
+            if self.metrics is not None:
+                self.metrics.mapping("engine.cd_sweep_block")[K] = \
+                    cd_sweep_block(K)
+            ctx = dataclasses.replace(
+                ctx, sweep=gram_sweep(ctx.G, ctx.c, ctx.L_ws))
+        return super().solve(ctx, beta0, eps, aux0=aux0, track=track)
 
     def prepare(self, ctx, beta0):
         return ctx.G @ beta0
@@ -495,14 +523,14 @@ class GramSolver(SubproblemSolver):
         return ctx.G @ beta
 
     def epoch(self, ctx, beta, aux):
-        if self.config.backend == "pallas" and _scalar_epoch_kernel_ok(
-                ctx.penalty, beta):
+        if self._kernel_epochs(ctx, beta):
             from repro.kernels import ops as kops
             from repro.kernels.common import penalty_params
             return kops.cd_epoch_gram(ctx.G, ctx.c, beta, aux, ctx.L_ws,
                                       type(ctx.penalty),
                                       penalty_params(ctx.penalty), epochs=1)
-        return cd_epoch_gram(ctx.G, ctx.c, beta, aux, ctx.L_ws, ctx.penalty)
+        return gram_sweep_epoch(ctx.sweep, beta, aux, ctx.penalty,
+                                self.axpy_per_column)
 
     def objective(self, ctx, beta, aux):
         return (0.5 * jnp.vdot(beta, aux) - jnp.vdot(ctx.c, beta)
@@ -564,6 +592,8 @@ class SolveEngine:
     One engine owns one jitted fused step (compiled per power-of-two bucket)
     plus one jitted multi-lambda chunk step, and records:
       retraces:    {bucket or ("chunk", bucket, n_lanes): trace count}
+      metrics["engine.cd_sweep_block"]: {bucket: coordinates per trip of
+                   the jax Gram CD sweep}, for every bucket it was traced at
       n_dispatches: fused-step launches (== outer iterations driven)
 
     Constructed with `mesh` (a jax Mesh with a data and a model axis) the
@@ -617,9 +647,10 @@ class SolveEngine:
     def n_dispatches(self, value: int):
         self.metrics.set_counter("engine.n_dispatches", value)
 
-    def _make_inner(self):
+    def _make_inner(self, axpy_per_column=False):
         cfg = self.config
-        return GramSolver(cfg) if cfg.gram else XbSolver(cfg)
+        return GramSolver(cfg, self.metrics, axpy_per_column) if cfg.gram \
+            else XbSolver(cfg)
 
     def _specs(self):
         """(X, y/Xb, beta/L/offset) PartitionSpecs on the engine's mesh."""
@@ -692,7 +723,7 @@ class SolveEngine:
         return sdf, grad, scores, kkt, gsupp, gcount, obj
 
     def _step_body(self, design, y, w, beta, Xb, L, offset, datafit, penalty,
-                   tol, eps_frac, bucket, obs=None):
+                   tol, eps_frac, bucket, obs=None, inner=None):
         """Fused: score -> select -> gather -> inner solve -> scatter.
 
         Each stage runs under a ``jax.named_scope`` of that name (``score``,
@@ -713,6 +744,8 @@ class SolveEngine:
         launch is nearly free. The covered flag asserts the selected working
         set retained the whole generalized support (it must, while the
         bucket policy keeps bucket >= |gsupp|).
+
+        ``inner`` is the subproblem solver (``_make_inner()`` when None).
 
         ``obs`` is an optional telemetry ring (repro.obs.rings, DESIGN.md
         §11.1): when given, the step records this iteration's
@@ -773,7 +806,7 @@ class SolveEngine:
             in_ws = gsupp[loc] if mine is None \
                 else jnp.where(mine, gsupp[loc], False)
             cov = _psum_if(jnp.sum(in_ws, dtype=jnp.int32), ma) == gcount0
-        inner = self._make_inner()
+        inner = inner or self._make_inner()
         track = obs is not None           # count Anderson accepts for the ring
         # the pass-through sdf wrapper would break the pallas kernels'
         # datafit-kind lookup; hand the inner solver the bare datafit
@@ -1051,13 +1084,16 @@ class SolveEngine:
             key = ("obs", key)
         self.retraces[key] = self.retraces.get(key, 0) + 1
         p_glob = design.shape[1]
+        # the lanes' Gram sweeps advance q one axpy per column: the faster
+        # layout under vmap on a TPU v5e (core/cd.py _blocked_sweep)
+        inner = self._make_inner(axpy_per_column=True)
 
         if self.mesh is None:
             def step(lam, beta, Xb, w_l, L_l, ring=None):
                 pen = dataclasses.replace(penalty, lam=lam)
                 return self._step_body(design, y, w_l, beta, Xb, L_l, offset,
                                        datafit, pen, tol, eps_frac, bucket,
-                                       obs=ring)
+                                       obs=ring, inner=inner)
 
             return self._chunk_loop(step, p_glob, lams, betas, Xbs, w, L,
                                     tol, max_outer, growth, bucket, obs=obs)
@@ -1081,7 +1117,7 @@ class SolveEngine:
                 pen = dataclasses.replace(penalty, lam=lam)
                 return self._step_body(design, y, w_l, beta, Xb, L_l, offset,
                                        datafit, pen, tol, eps_frac, bucket,
-                                       obs=ring)
+                                       obs=ring, inner=inner)
 
             return self._chunk_loop(step, p_glob, lams, betas, Xbs, w, L,
                                     tol, max_outer, growth, bucket, obs=obs)

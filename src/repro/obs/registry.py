@@ -13,6 +13,7 @@ BENCH_engine.json budget guard.
 Naming scheme (dotted, lowercase):
 
   engine.retraces            mapping   {bucket key: compile count}
+  engine.cd_sweep_block      mapping   {bucket: Gram CD sweep block size}
   engine.n_dispatches        counter   fused-step launches
   solve.n_host_syncs         counter   blocking readbacks of one solve
   solve.n_outer / n_epochs   counter   per-solve loop totals

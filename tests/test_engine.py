@@ -101,6 +101,61 @@ def test_single_host_sync_per_outer_iteration(lasso_data):
     assert warm.n_host_syncs == len(warm.kkt_history) + 1
 
 
+def test_blocked_sweep_does_the_plain_sweeps_work(monkeypatch):
+    """At a reduced AR(1) shape, solve and cross_val_path with the blocked
+    Gram CD sweep do the plain one-coordinate-per-trip sweep's work: the
+    same outer iterations, epochs and support, and in float64 for the
+    Lasso the same coefficients bit for bit, alone and under the chunk
+    step's lane vmap, each in its layout of q's update.
+    engine.cd_sweep_block names the block size of every bucket that ran."""
+    from repro.core import cross_val_path
+    from repro.core import engine as engine_mod
+    from repro.core.cd import cd_sweep_block
+    from repro.kernels.ref import cd_epoch_gram_ref
+
+    def plain_epoch(sweep, b, q, pen, axpy_per_column):
+        K = b.shape[0]
+        return cd_epoch_gram_ref(sweep.G[:K, :K], sweep.c[:K], b, q,
+                                 sweep.L[:K], pen)
+    X, y, _ = make_correlated_design(n=200, p=500, n_nonzero=50, rho=0.6,
+                                     snr=5.0, seed=3)
+    X, y = jnp.asarray(X), jnp.asarray(y)
+    lam = lambda_max(X, y) / 20
+
+    def run():
+        es, eg = make_engine(L1(lam), Quadratic()), \
+            make_engine(L1(lam), Quadratic())
+        res = solve(X, y, Quadratic(), L1(lam), tol=1e-6, engine=es)
+        grid = cross_val_path(X, y, Quadratic(), L1(1.0), n_lambdas=6,
+                              lambda_min_ratio=0.05, cv=3, vmap_chunk=2,
+                              tol=1e-6, engine=eg)
+        return res, grid, es, eg
+
+    res, grid, es, eg = run()
+    with monkeypatch.context() as m:
+        m.setattr(engine_mod, "gram_sweep_epoch", plain_epoch)
+        res0, grid0, _, _ = run()
+    assert res.beta.dtype == np.float64
+    assert (res.n_outer, res.n_epochs) == (res0.n_outer, res0.n_epochs)
+    np.testing.assert_array_equal(np.asarray(res.beta) != 0,
+                                  np.asarray(res0.beta) != 0)
+    np.testing.assert_array_equal(res.beta, res0.beta)
+    assert grid.n_outer == grid0.n_outer
+    np.testing.assert_array_equal(grid.n_epochs, grid0.n_epochs)
+    np.testing.assert_array_equal(np.asarray(grid.betas) != 0,
+                                  np.asarray(grid0.betas) != 0)
+    np.testing.assert_array_equal(grid.betas, grid0.betas)
+    for engine in (es, eg):
+        buckets = set()
+        for key in engine.retraces:
+            while isinstance(key, tuple):
+                key = key[-1] if key[0] != "chunk" else key[1]
+            buckets.add(key)
+        assert len(buckets) > 1
+        assert engine.metrics.mapping("engine.cd_sweep_block") == \
+            {b: cd_sweep_block(b) for b in buckets}
+
+
 # ------------------------------------------------------- solve() edge cases
 def test_solve_max_outer_zero_no_crash(lasso_data):
     X, y, _ = lasso_data

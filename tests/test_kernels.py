@@ -1,17 +1,23 @@
 """Pallas kernel tests: shape/dtype sweeps, allclose against ref.py oracles.
 
 Kernels run with interpret=True on CPU (assignment contract); the oracles are
-the pure-jnp implementations in repro.kernels.ref.
+the pure-jnp implementations in repro.kernels.ref. The blocked Gram sweep of
+the jax backend (repro.core.cd) is held to the same oracle.
 """
+from dataclasses import dataclass
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.cd import (_blocked_sweep, cd_epoch_gram, cd_sweep_block,
+                           gram_sweep, gram_sweep_epoch)
 from repro.core.datafits import Logistic, Quadratic, QuadraticSVC
-from repro.core.penalties import MCP, SCAD, L05, L1, L1L2, Box
+from repro.core.penalties import (MCP, SCAD, L05, L1, L1L2, L23, BlockL1,
+                                  BlockMCP, Box, soft_threshold)
 from repro.kernels import ops, ref
-from repro.kernels.common import penalty_params
+from repro.kernels.common import SCALAR_COORD_PENALTIES, penalty_params
 
 PENALTIES = [L1(0.11), L1L2(0.11, 0.6), MCP(0.11, 3.0), SCAD(0.11, 3.7),
              Box(0.8), L05(0.05)]
@@ -48,6 +54,79 @@ def test_cd_epoch_gram_matches_ref(penalty, K, dtype):
                                             epochs=epochs)
         np.testing.assert_allclose(beta_k, beta_r, atol=_tol(dtype), rtol=1e-5)
         np.testing.assert_allclose(q_k, q_r, atol=_tol(dtype), rtol=1e-5)
+
+
+@dataclass(frozen=True)
+class _WeightedL1:
+    """L1 with a weight per coordinate, reached only through prox_at."""
+    lam: float
+    weights: jax.Array
+
+    def prox_at(self, x, step, j):
+        return soft_threshold(x, step * self.lam * self.weights[j])
+
+
+def _sweep_penalties(K, dtype):
+    scalar = [L1(0.11), L1L2(0.11, 0.6), MCP(0.11, 3.0), SCAD(0.11, 3.7),
+              Box(0.8), L05(0.05), L23(0.05)]
+    assert {type(p) for p in scalar} == set(SCALAR_COORD_PENALTIES)
+    weights = jnp.asarray(np.linspace(0.2, 2.0, K).astype(dtype))
+    return ([(p, None) for p in scalar] + [(_WeightedL1(0.11, weights), None)]
+            + [(BlockL1(0.11), 3), (BlockMCP(0.11, 3.0), 3)])
+
+
+@pytest.mark.parametrize("case", range(10), ids=[
+    "L1", "L1L2", "MCP", "SCAD", "Box", "L05", "L23", "prox_at",
+    "BlockL1-T3", "BlockMCP-T3"])
+@pytest.mark.parametrize("K", [8, 13, 64, 200, 1024])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_blocked_gram_sweep_matches_plain_loop(case, K, dtype):
+    """The blocked sweep of cd_epoch_gram (B coordinates a loop trip) gives
+    the plain one-coordinate-per-trip loop's beta and q: scalar [K] and
+    multitask [K, T] coordinates, K a multiple of B and not, alone and
+    under a vmap over lanes in the layout of q's update the chunk step
+    uses (one axpy per column). Coordinates with
+    L = 0 are inert, which the padding to a multiple of B rests on: they
+    keep their beta, and padded ones come back untouched."""
+    penalty, T = _sweep_penalties(K, dtype)[case]
+    G, c, beta0, q0, L = _gram_inputs(K, dtype)
+    if T is not None:
+        rng = np.random.default_rng(1)
+        beta0 = jnp.asarray((rng.standard_normal((K, T)) * 0.1).astype(dtype))
+        c = jnp.asarray(np.asarray(c)[:, None]
+                        * np.linspace(0.5, 1.5, T).astype(dtype))
+        q0 = G @ beta0
+    L = L.at[::7].set(0.0)
+    plain = jax.jit(lambda b, q: ref.cd_epoch_gram_ref(G, c, b, q, L,
+                                                       penalty))
+    one = lambda b, q: cd_epoch_gram(G, c, b, q, L, penalty)
+
+    def lanes(b, q):                   # a vmap over lanes, as the chunk step
+        epoch = lambda b, q: gram_sweep_epoch(gram_sweep(G, c, L), b, q,
+                                              penalty, axpy_per_column=True)
+        bb, qq = jax.vmap(epoch)(jnp.stack([b, b]), jnp.stack([q, q]))
+        return bb[1], qq[1]
+
+    for sweep in (jax.jit(one), jax.jit(lanes)):
+        for epochs in (1, 5):
+            beta, q = beta_r, q_r = beta0, q0
+            for _ in range(epochs):
+                beta, q = sweep(beta, q)
+                beta_r, q_r = plain(beta_r, q_r)
+            assert beta.shape == beta0.shape and q.shape == q0.shape
+            np.testing.assert_allclose(beta, beta_r, atol=_tol(dtype),
+                                       rtol=1e-5)
+            np.testing.assert_allclose(q, q_r, atol=_tol(dtype), rtol=1e-5)
+            np.testing.assert_array_equal(beta[::7], beta0[::7])
+    B = cd_sweep_block(K)
+    Kp = -(-K // B) * B
+    if Kp != K:
+        pad = lambda v: jnp.pad(v, [(0, Kp - K)] + [(0, 0)] * (v.ndim - 1))
+        beta_p, q_p = jax.jit(
+            lambda *a: _blocked_sweep(gram_sweep(*a[:3]), *a[3:], penalty,
+                                      B))(G, c, L, pad(beta0), pad(q0))
+        np.testing.assert_array_equal(beta_p[K:], 0.0)
+        np.testing.assert_array_equal(q_p[K:], 0.0)
 
 
 @pytest.mark.parametrize("penalty", [L1(0.07), MCP(0.07, 3.0), Box(0.9)],

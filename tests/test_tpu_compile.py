@@ -8,7 +8,9 @@ chip) at the widths ``chip_smoke.py`` drives: the fused working-set head at
 n=10,000, p=20,000, ws=256; the Gram CD epoch at K=1024 for every penalty
 the kernel codec accepts; the Xb CD epoch at K=256, n=10,000 for the
 quadratic and logistic datafits. Each compiled program must hold a Mosaic
-kernel (``tpu_custom_call``), i.e. nothing fell back to interpret mode.
+kernel (``tpu_custom_call``), i.e. nothing fell back to interpret mode. The
+whole fused step of the jax backend compiles at the dense benchmark's
+widths, with its blocked Gram CD sweep.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers all import
@@ -135,3 +137,31 @@ def test_outer_step_ops_name_one_stage(one_chip, compile_f32):
     assert {"score", "select", "gather", "inner_solve", "scatter"} \
         <= set(named)
     assert len(unnamed) * 5 < len(named), unnamed
+
+
+@pytest.mark.parametrize("bucket", [512, 1024])
+def test_outer_step_compiles_for_v5e(one_chip, compile_f32, bucket):
+    """The whole fused step ``_outer_step`` at the dense benchmark's widths
+    (n=1,000, p=2,000, float32) at the buckets its solves reach: it
+    compiles for a v5e, records the Gram CD sweep's block size for the
+    bucket, and the sweep's loop runs bucket / B trips an epoch."""
+    import re
+
+    from repro.core import L1, Quadratic, make_engine
+    from repro.core.cd import cd_sweep_block
+    from repro.core.engine import DenseDesign
+    n, p = 1_000, 2_000
+    s = lambda *shape: _spec(one_chip, *shape)
+    engine = make_engine(L1(0.1), Quadratic())
+    text = compile_f32(engine._jstep, DenseDesign(s(n, p)), s(n), None,
+                       s(p), s(n), s(p), s(p), Quadratic(), L1(0.1), 1e-4,
+                       0.3, bucket=bucket, obs=None)
+    B = cd_sweep_block(bucket)
+    assert engine.metrics.mapping("engine.cd_sweep_block") == {bucket: B}
+    conds = set(re.findall(r"condition=%([\w.\-]+)", text))
+    bounds = set()
+    for cond in conds:
+        body = re.search(r"\n%" + re.escape(cond) + r" .*?\n\}", text, re.S)
+        bounds |= {int(v) for v in
+                   re.findall(r"s32\[\]\S* constant\((\d+)\)", body.group(0))}
+    assert bucket // B in bounds and bucket not in bounds, sorted(bounds)
