@@ -71,6 +71,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 from repro.launch.shardings import design_specs, ring_spec, task_spec
+from repro.obs import collectives
 from repro.obs.registry import MetricsRegistry
 from repro.obs.rings import gap_supported, quadratic_l1_gap
 
@@ -352,7 +353,7 @@ class WorkingSetContext:
 
 def _psum_if(x, axis):
     """psum over `axis`, statically elided for unsplit (size-1) axes."""
-    return x if axis is None else jax.lax.psum(x, axis)
+    return x if axis is None else collectives.psum(x, axis)
 
 
 class _ShardedDatafit:
@@ -594,6 +595,10 @@ class SolveEngine:
       retraces:    {bucket or ("chunk", bucket, n_lanes): trace count}
       metrics["engine.cd_sweep_block"]: {bucket: coordinates per trip of
                    the jax Gram CD sweep}, for every bucket it was traced at
+      metrics["engine.collective_bytes"]: {bucket: bytes one chip sends
+                   through the fused step's collectives outside the inner
+                   solve's loops (repro.obs.collectives), as last traced;
+                   0 on one device}
       n_dispatches: fused-step launches (== outer iterations driven)
 
     Constructed with `mesh` (a jax Mesh with a data and a model axis) the
@@ -711,7 +716,7 @@ class SolveEngine:
                                   use_fixed_point=cfg.use_fp_score)
         kkt = jnp.max(scores)
         if ma is not None:
-            kkt = jax.lax.pmax(kkt, ma)
+            kkt = collectives.pmax(kkt, ma)
         gsupp = penalty.generalized_support(beta)
         gcount = _psum_if(jnp.sum(gsupp, dtype=jnp.int32), ma)
         if ma is None:
@@ -719,7 +724,8 @@ class SolveEngine:
                 penalty.value(beta)
         else:
             obj = sdf.value(Xb, y, w) + \
-                jax.lax.psum(_lin(offset, beta) + penalty.value(beta), ma)
+                collectives.psum(_lin(offset, beta) + penalty.value(beta),
+                                 ma)
         return sdf, grad, scores, kkt, gsupp, gcount, obj
 
     def _step_body(self, design, y, w, beta, Xb, L, offset, datafit, penalty,
@@ -831,7 +837,7 @@ class SolveEngine:
                     # replicated (weights enter as X_ws^T diag(w) X_ws on
                     # the local rows)
                     Xw = X_ws if w is None else w[:, None] * X_ws
-                    G = jax.lax.psum(X_ws.T @ Xw, da)
+                    G = collectives.psum(X_ws.T @ Xw, da)
                     if sdf.sample_mean:
                         G = G / n_glob
                 # linearize at the incoming iterate: grad_ws(b) = G (b - b0)
@@ -844,8 +850,9 @@ class SolveEngine:
                                         pen_ws, G=G, c=c)
 
                 def run(_):
-                    beta_ws, _, n_ep, _, n_acc = inner.solve(
-                        ctx, beta_ws0, eps_in, aux0=q0, track=track)
+                    with collectives.untallied():
+                        beta_ws, _, n_ep, _, n_acc = inner.solve(
+                            ctx, beta_ws0, eps_in, aux0=q0, track=track)
                     return beta_ws, n_ep, n_acc
 
                 def skip(_):
@@ -866,8 +873,9 @@ class SolveEngine:
                 def run(_):
                     # Xb is shared outer-loop state: enter with the
                     # caller's Xb
-                    beta_ws, Xb2, n_ep, _, n_acc = inner.solve(
-                        ctx, beta_ws0, eps_in, aux0=Xb, track=track)
+                    with collectives.untallied():
+                        beta_ws, Xb2, n_ep, _, n_acc = inner.solve(
+                            ctx, beta_ws0, eps_in, aux0=Xb, track=track)
                     return beta_ws, Xb2, n_ep, n_acc
 
                 def skip(_):
@@ -952,12 +960,18 @@ class SolveEngine:
         if obs is not None:
             key = ("obs", key)
         self.retraces[key] = self.retraces.get(key, 0) + 1
-        if self.mesh is not None:
-            return self._sharded_step(design, y, w, beta, Xb, L, offset,
+        sizes = {} if self.mesh is None else self.mesh.shape
+        with collectives.tally(sizes) as sent:
+            if self.mesh is not None:
+                out = self._sharded_step(design, y, w, beta, Xb, L, offset,
+                                         datafit, penalty, tol, eps_frac,
+                                         bucket, obs=obs)
+            else:
+                out = self._step_body(design, y, w, beta, Xb, L, offset,
                                       datafit, penalty, tol, eps_frac,
                                       bucket, obs=obs)
-        return self._step_body(design, y, w, beta, Xb, L, offset, datafit,
-                               penalty, tol, eps_frac, bucket, obs=obs)
+        self.metrics.mapping("engine.collective_bytes")[bucket] = sent.bytes
+        return out
 
     def _probe(self, design, y, w, beta, Xb, L, offset, datafit, penalty):
         """Pre-loop probe: kkt/|gsupp|/obj of the initial iterate (sizes the

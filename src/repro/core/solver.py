@@ -382,6 +382,9 @@ def solve(X, y, datafit, penalty, *, tol=1e-6, max_outer=50, max_epochs=1000,
         reg = res.diagnostics.registry
         reg.set_counter("solve.n_outer", res.n_outer)
         reg.set_counter("solve.n_epochs", res.n_epochs)
+        sent = engine.metrics.mapping("engine.collective_bytes")
+        reg.set_counter("solve.collective_bytes",
+                        sum(sent.get(b, 0) for b in res.ws_history))
     if obs is not None:
         obs.registry.inc("solve.count")
         obs.note_solve(res.diagnostics)

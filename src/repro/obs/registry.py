@@ -14,9 +14,12 @@ Naming scheme (dotted, lowercase):
 
   engine.retraces            mapping   {bucket key: compile count}
   engine.cd_sweep_block      mapping   {bucket: Gram CD sweep block size}
+  engine.collective_bytes    mapping   {bucket: bytes a chip sends per step}
   engine.n_dispatches        counter   fused-step launches
   solve.n_host_syncs         counter   blocking readbacks of one solve
   solve.n_outer / n_epochs   counter   per-solve loop totals
+  solve.collective_bytes     counter   the steps' collective_bytes, summed
+                                       over the solve's n_outer outers
   path.retraces              mapping   PathResult compat view
   path.n_dispatches          counter   PathResult compat view
   grid.n_host_syncs          counter   cross_val_path sweep totals

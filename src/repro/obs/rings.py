@@ -31,6 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import collectives
+
 __all__ = ["TelemetryRing", "FIELDS", "gap_supported", "quadratic_l1_gap",
            "lasso_duality_gap"]
 
@@ -130,12 +132,12 @@ def quadratic_l1_gap(y, Xb, grad, obj, n_glob, lam, data_axis, model_axis):
     r = y - Xb
     gmax = jnp.max(jnp.abs(grad))
     if model_axis is not None:
-        gmax = jax.lax.pmax(gmax, model_axis)
+        gmax = collectives.pmax(gmax, model_axis)
     yr = jnp.vdot(y, r)
     rr = jnp.vdot(r, r)
     if data_axis is not None:
-        yr = jax.lax.psum(yr, data_axis)
-        rr = jax.lax.psum(rr, data_axis)
+        yr = collectives.psum(yr, data_axis)
+        rr = collectives.psum(rr, data_axis)
     scale = jnp.minimum(1.0, lam / jnp.maximum(gmax, 1e-300))
     dual = scale * yr / n_glob - scale * scale * rr / (2.0 * n_glob)
     return obj - dual

@@ -29,10 +29,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.obs import collectives
+
 
 def _psum_if(x, axis):
     """psum over `axis`, statically elided for unsplit (None) axes."""
-    return x if axis is None else jax.lax.psum(x, axis)
+    return x if axis is None else collectives.psum(x, axis)
 
 
 # ---------------------------------------------------------------- score pass

@@ -165,3 +165,65 @@ def test_outer_step_compiles_for_v5e(one_chip, compile_f32, bucket):
         bounds |= {int(v) for v in
                    re.findall(r"s32\[\]\S* constant\((\d+)\)", body.group(0))}
     assert bucket // B in bounds and bucket not in bounds, sorted(bounds)
+
+
+@pytest.fixture(scope="module")
+def mesh_1x4(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+
+
+def test_sharded_step_fits_a_v5e_chip_at_gwas_size(mesh_1x4, compile_f32):
+    """The fused step of the (1, 4) feature-sharded engine at the GWAS
+    cell's size (n=50,000, p=120,000: a 6 GB float32 shard a chip, bucket
+    1,024) compiles for a v5e 2x2 with temporaries of a few working-set
+    blocks, not a second copy of the shard: the column gather copies the K
+    columns one by one instead of the one-op gather's row slices of the
+    whole shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import L1, Quadratic, make_engine
+    from repro.core.engine import DenseDesign
+    n, p, bucket = 50_000, 120_000, 1_024
+
+    def s(spec, *shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32,
+                                    sharding=NamedSharding(mesh_1x4, spec))
+
+    engine = make_engine(L1(0.1), Quadratic(), mesh=mesh_1x4)
+    args = (DenseDesign(s(P("data", "model"), n, p)), s(P("data"), n), None,
+            s(P("model"), p), s(P("data"), n), s(P("model"), p),
+            s(P("model"), p), Quadratic(), L1(0.1), 1e-4, 0.3)
+    with jax.enable_x64(False):
+        compiled = engine._jstep.lower(*args, bucket=bucket,
+                                       obs=None).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 6e9
+    assert mem.temp_size_in_bytes < 4 * n * bucket * 4     # < 0.82 GB
+    assert "mini-gather" not in compiled.as_text()
+    assert engine.metrics.mapping("engine.collective_bytes")[bucket] > 0
+
+
+def test_gwas_design_is_made_a_block_at_a_time(mesh_1x4, compile_f32):
+    """The GWAS generator makes each chip's 6 GB shard holding no more
+    than the shard and one block of columns."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from bench.data import gwas_dense
+    n, p = 50_000, 120_000
+    fill = jax.jit(jax.shard_map(
+        lambda key, laws: gwas_dense._block_fill(
+            key, laws, jax.lax.axis_index("model") * laws.shape[1], n),
+        mesh=mesh_1x4, in_specs=(P(), P(None, "model")),
+        out_specs=P(None, "model"), check_vma=False))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    args = (jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                 sharding=NamedSharding(mesh_1x4, P())),
+            jax.ShapeDtypeStruct((4, p), jnp.float32,
+                                 sharding=NamedSharding(mesh_1x4,
+                                                        P(None, "model"))))
+    with jax.enable_x64(False):
+        mem = fill.lower(*args).compile().memory_analysis()
+    assert mem.output_size_in_bytes == pytest.approx(n * p // 4 * 4, rel=1e-2)
+    assert mem.temp_size_in_bytes <= 1.05 * n * gwas_dense.BLOCK * 4
